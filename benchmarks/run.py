@@ -4,12 +4,17 @@
     PYTHONPATH=src python -m benchmarks.run --quick    # smoke subset
     PYTHONPATH=src python -m benchmarks.run --full     # paper-scale n (hours)
 
-Writes benchmarks/results/*.json + benchmarks/results/REPORT.md.
+Writes benchmarks/results/*.json + benchmarks/results/REPORT.md. Every
+module runs in this one process (one process per chip); the exit status is
+1 when any module failed.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (fig5, fig6, fig7_8, fig9, fig10, pc_batch, pc_cit,
                pc_distributed, pc_engines, pc_grid, pc_hillclimb, pc_serve,
@@ -40,8 +45,10 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     sections = []
+    failed = []
     for name, mod in MODULES:
         if args.only and args.only != name:
             continue
@@ -51,15 +58,20 @@ def main(argv=None):
             dt = time.perf_counter() - t0
             print(f"[bench] {name:10s} ok in {dt:6.1f}s", flush=True)
             sections.append(md)
-        except Exception as e:  # keep the harness running; report at end
+        except Exception as e:  # finish the report, then exit non-zero
             print(f"[bench] {name:10s} FAILED: {e!r}", flush=True)
             sections.append(f"### {name} — FAILED: {e!r}")
+            failed.append(name)
     RESULTS.mkdir(parents=True, exist_ok=True)
     report = "# Benchmark report (paper tables/figures analogues)\n\n" + "\n\n".join(sections) + "\n"
     (RESULTS / "REPORT.md").write_text(report)
     print(f"[bench] report -> {RESULTS / 'REPORT.md'}")
     print(report)
+    if failed:
+        print(f"[bench] FAILED modules: {', '.join(failed)}", flush=True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

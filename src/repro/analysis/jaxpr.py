@@ -45,10 +45,9 @@ CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback", "debug_print
 
 # --------------------------------------------------------------------- walk
 def _sub_jaxprs(params: dict):
-    import jax.core as jcore
+    from jax.extend.core import ClosedJaxpr as closed
+    from jax.extend.core import Jaxpr as open_
 
-    closed = getattr(jcore, "ClosedJaxpr", ())
-    open_ = getattr(jcore, "Jaxpr", ())
     for v in params.values():
         stack = [v]
         while stack:
@@ -78,11 +77,10 @@ def trace(fn: Callable, *args, **kwargs):
     import functools
 
     import jax
-    from jax.experimental import enable_x64
 
     if kwargs:
         fn = functools.partial(fn, **kwargs)
-    with enable_x64():
+    with jax.enable_x64(True):
         return jax.make_jaxpr(fn)(*args).jaxpr
 
 

@@ -65,7 +65,7 @@ _TRACING_TAILS = {
 STATIC_ARGNAME_ALLOWLIST = {
     "ell", "n_chunk", "n_max", "r", "q", "use_kernel", "bm", "bi", "bj",
     "bk", "bn", "bs", "bp", "npr", "tb", "jitter", "interpret",
-    "vote_chunk", "depth",
+    "vote_chunk", "depth", "n_prime",
 }
 
 #: Seam registry: Finding.key -> one-line justification. Keys are

@@ -42,6 +42,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.scipy.special import ndtri
 
+#: f32 matmuls here run at full f32 precision: the TPU's default rounds
+#: operands to bf16, which moves C and partial correlations by ~1e-3 and
+#: flips tests near τ. On the CPU this changes no bit.
+_HI = jax.lax.Precision.HIGHEST
+
 #: Hard cap on one G² worklist cell's contingency-table width
 #: K = r^(ℓ+2): the table is unrolled in the kernel/reference reduction,
 #: so K bounds both trace size and VMEM accumulator rows.
@@ -49,9 +54,15 @@ MAX_G2_TABLE = 4096
 
 
 def fisher_z(rho: jax.Array) -> jax.Array:
-    """|½ ln((1+ρ)/(1−ρ))| = |atanh ρ|, with clipping for |ρ|→1 (Eq. 6)."""
+    """|½ ln((1+ρ)/(1−ρ))| = |atanh ρ|, with clipping for |ρ|→1 (Eq. 6).
+
+    Written as ½(log1p ρ − log1p(−ρ)) rather than ``jnp.arctanh``: Mosaic
+    has no atanh lowering, and this is the expansion XLA applies to atanh,
+    so the jnp engines, the kernel references and the Pallas kernels (which
+    all call this function) share one op sequence and stay bit-identical to
+    the former ``arctanh`` decisions on the CPU."""
     rho = jnp.clip(rho, -0.9999999, 0.9999999)
-    return jnp.abs(jnp.arctanh(rho))
+    return jnp.abs(0.5 * (jnp.log1p(rho) - jnp.log1p(-rho)))
 
 
 def threshold(m: int, ell: int, alpha: float, *,
@@ -100,14 +111,14 @@ def pseudo_inverse(m2: jax.Array) -> jax.Array:
     Cholesky would need column pruning; following pcalg practice we add a
     tiny ridge — real gene-expression matrices are full rank up to noise.
     """
-    mt_m = jnp.einsum("...ji,...jk->...ik", m2, m2)
+    mt_m = jnp.einsum("...ji,...jk->...ik", m2, m2, precision=_HI)
     eye = jnp.eye(m2.shape[-1], dtype=m2.dtype)
     ridge = 1e-10 * jnp.trace(mt_m, axis1=-2, axis2=-1)[..., None, None] + 1e-30
     l = jnp.linalg.cholesky(mt_m + ridge * eye)
-    lt_l = jnp.einsum("...ji,...jk->...ik", l, l)
+    lt_l = jnp.einsum("...ji,...jk->...ik", l, l, precision=_HI)
     r = jnp.linalg.inv(lt_l)
     return jnp.einsum(
-        "...ij,...jk,...kl,...ml,...nm->...in", l, r, r, l, m2
+        "...ij,...jk,...kl,...ml,...nm->...in", l, r, r, l, m2, precision=_HI
     )
 
 
@@ -134,14 +145,14 @@ def partial_corr_single(
     cj_s = c[j, s]
     if robust:
         g = pseudo_inverse(m2)
-        gi = g @ ci_s
-        gj = g @ cj_s
+        gi = jnp.matmul(g, ci_s, precision=_HI)
+        gj = jnp.matmul(g, cj_s, precision=_HI)
     else:
         gi = solve_spd(m2, ci_s)
         gj = solve_spd(m2, cj_s)
-    h01 = c[i, j] - ci_s @ gj
-    h00 = c[i, i] - ci_s @ gi
-    h11 = c[j, j] - cj_s @ gj
+    h01 = c[i, j] - jnp.matmul(ci_s, gj, precision=_HI)
+    h00 = c[i, i] - jnp.matmul(ci_s, gi, precision=_HI)
+    h11 = c[j, j] - jnp.matmul(cj_s, gj, precision=_HI)
     denom = jnp.sqrt(jnp.maximum(h00 * h11, 1e-30))
     return h01 / denom
 
@@ -157,7 +168,7 @@ def correlation_from_samples(x: jax.Array) -> jax.Array:
     xc = x - mu
     std = jnp.sqrt(jnp.mean(xc * xc, axis=0, keepdims=True))
     xn = xc / jnp.maximum(std, 1e-30)
-    c = (xn.T @ xn) / x.shape[0]
+    c = jnp.matmul(xn.T, xn, precision=_HI) / x.shape[0]
     # exact-1 diagonal guards atanh in level 0
     return jnp.clip(c, -1.0, 1.0).at[jnp.arange(x.shape[1]), jnp.arange(x.shape[1])].set(1.0)
 

@@ -66,7 +66,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import obs
 
@@ -183,8 +182,8 @@ def _gather_cols_fn(mesh: Mesh):
     (n_l, k) slice of C[:, cols] all-gathered to a replicated (n_pad, k)."""
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(AXIS), P()), out_specs=P(),
-        check_rep=False,
+        jax.shard_map, mesh=mesh, in_specs=(P(AXIS), P()), out_specs=P(),
+        check_vma=False,
     )
     def _gather(c_rows, cols):
         return jax.lax.all_gather(c_rows[:, cols], AXIS, tiled=True)
@@ -200,11 +199,11 @@ def _tests_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int):
     program across levels and calls (Mesh is hashable)."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(AXIS), P(AXIS), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def _tests(c, adj, compact_l, counts_l, t0, tau):
         rows_l = _shard_rows_ids(compact_l.shape[0])
@@ -231,11 +230,11 @@ def _tests_sharded_c_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int, k: int,
     if cached:
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P(AXIS), P(), P(), P(AXIS), P(AXIS), P(), P(), P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def _tests(c_rows, c_cols, adj, compact_l, counts_l, col_pos, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
@@ -249,11 +248,11 @@ def _tests_sharded_c_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int, k: int,
     else:
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P(AXIS), P(), P(AXIS), P(AXIS), P(), P(), P(), P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def _tests(c_rows, adj, compact_l, counts_l, cols, col_pos, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
@@ -301,8 +300,8 @@ def _grid_tests_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int,
     if shard_c:
         in_specs = (P(AXIS), P(), P(), P(AXIS), P(AXIS), P(), P(), P())
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(), P(), P()), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(), P(), P()), check_vma=False)
         def _tests(c_rows, c_cols, adj, compact_l, counts_l, col_pos, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
             return _gather_winners(*chunk_s_grid_tests_cols(
@@ -312,9 +311,9 @@ def _grid_tests_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int,
 
     else:
 
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(), P(), P(AXIS), P(AXIS), P(), P()),
-                           out_specs=(P(), P(), P()), check_rep=False)
+                           out_specs=(P(), P(), P()), check_vma=False)
         def _tests(c, adj, compact_l, counts_l, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
             return _gather_winners(*chunk_s_grid_tests(
@@ -341,8 +340,8 @@ def _grid_fused_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int,
         in_specs = (P(AXIS), P(), P(), sep_spec, P(AXIS), P(AXIS), P(), P(),
                     P(), P())
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(), sep_spec), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(), sep_spec), check_vma=False)
         def _fused(c_rows, c_cols, adj, sep, compact_l, counts_l, col_pos,
                    compact_full, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
@@ -357,8 +356,8 @@ def _grid_fused_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int,
         in_specs = (P(AXIS), P(), sep_spec, P(AXIS), P(AXIS), P(), P(), P(),
                     P(), P())
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(), sep_spec), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(), sep_spec), check_vma=False)
         def _fused(c_rows, adj, sep, compact_l, counts_l, cols, col_pos,
                    compact_full, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
@@ -373,8 +372,8 @@ def _grid_fused_fn(mesh: Mesh, ell: int, n_chunk: int, n_max: int,
     else:
         in_specs = (P(), P(), sep_spec, P(AXIS), P(AXIS), P(), P(), P())
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(), sep_spec), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(), sep_spec), check_vma=False)
         def _fused(c, adj, sep, compact_l, counts_l, compact_full, t0, tau):
             rows_l = _shard_rows_ids(compact_l.shape[0])
             winners = _gather_winners(*chunk_s_grid_tests(
@@ -402,11 +401,11 @@ def _commit_fn(mesh: Mesh, ell: int, shard_sep: bool):
     sep_spec = P(AXIS) if shard_sep else P()
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), sep_spec, P(), P(), P(), P()),
         out_specs=(P(), sep_spec),
-        check_rep=False,
+        check_vma=False,
     )
     def _commit(adj, sep, compact_full, t_win, rem, s_win):
         n = adj.shape[0]
@@ -557,13 +556,24 @@ def run_level_sharded(c, adj, sep, ell, tau, mesh,
 
     stats["chunks"] = chunks
     stats["dispatches"] = dispatches
+    if shard_sep:
+        # the sepset blocks the commit shard_map wrote
+        stats["sep_row_blocks"] = _row_blocks(sep)
     if shard_c:
+        stats["c_row_blocks"] = _row_blocks(c)  # the C this level read
         if col_cache is None:
             stats["col_gathers"] = chunks  # one collective per chunk body
         # bytes the column collective(s) shipped this level (fp32)
         stats["col_gather_bytes"] = stats["col_gathers"] * (n + pad) * k * 4
     obs.record_level_stats(stats, level=ell, layout="sharded")
     return adj, sep, stats
+
+
+def _row_blocks(a) -> list:
+    """(device id, first row, end row) of each of a's addressable shards —
+    layout metadata only, no device sync."""
+    return sorted((s.device.id, *s.index[0].indices(a.shape[0])[:2])
+                  for s in a.addressable_shards)
 
 
 def _speculative_dispatch(c, adj, ell, tau, mesh, prev_npr_b, n,
@@ -685,7 +695,7 @@ def pc_distributed(
         if c is None:
             assert x is not None
             m = int(x.shape[0])
-            c = correlation_from_samples(jnp.asarray(x))
+            c = correlation_from_samples(jnp.asarray(x, jnp.float32))
         c = jnp.asarray(c, jnp.float32)
         n = c.shape[0]
         lmax = min(max_level if max_level is not None else MAX_LEVEL,
@@ -758,7 +768,8 @@ def pc_distributed(
 
         if shard_sep:
             sep = sep[:n]  # drop shard padding before orientation/export
-        cpdag = cpdag_from_skeleton(adj, sep)
+        max_deg = int(jax.device_get(jnp.max(jnp.sum(adj, axis=1))))
+        cpdag = cpdag_from_skeleton(adj, sep, n_prime=min(n, L.bucket_npr(max(max_deg, 1))))
         run = PCRun(
             adj=np.asarray(jax.device_get(adj)),
             cpdag=np.asarray(jax.device_get(cpdag)),

@@ -58,9 +58,15 @@ import numpy as np
 from .cit import fisher_z
 from .combinadics import binom_table
 
+#: f32 contractions of the CI math run at full f32 precision: at the TPU's
+#: default precision a matmul rounds its operands to bf16 (~3 digits),
+#: enough to flip Fisher-z decisions near τ against the f32 Pallas kernels
+#: and the float64 oracle. On the CPU this changes no bit.
+_HI = jax.lax.Precision.HIGHEST
+
 def _rank_dtype():
     """int64 ranks when x64 is on; int32 otherwise. C(n',l) beyond 2^29
-    requires jax_enable_x64 (the pc_run launcher enables it)."""
+    requires jax_enable_x64 (``JAX_ENABLE_X64=1``)."""
     return jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
 
 
@@ -241,11 +247,15 @@ def gather_s(c, adj, compact, counts, rows, ranks, *, ell: int, n_max: int):
     n_chunk = ranks.shape[0]
     s_ids, valid_set = plan_sets(compact, counts, ranks, ell=ell, n_max=n_max, n=n)
 
+    # the gathers index with the ℓ set members as the LEADING axis and move
+    # it last afterwards: with ℓ minor, compiling one chunk program for a
+    # v5e spent ~25 s on the C[j, S] gather (n=150, ℓ=3), with ℓ major ~3 s.
+    s_t = jnp.moveaxis(s_ids, -1, 0)  # (ell, n_l, T)
     # M2 = C[S,S] — gathered ONCE per (row, set): the cuPC-S sharing.
-    m2 = c[s_ids[..., :, None], s_ids[..., None, :]]  # (n_l,T,ell,ell)
-    ci_s = c[rows[:, None, None], s_ids]  # (n_l,T,ell)
+    m2 = jnp.moveaxis(c[s_t[:, None], s_t[None, :]], (0, 1), (-2, -1))  # (n_l,T,ell,ell)
+    ci_s = jnp.moveaxis(c[rows[None, :, None], s_t], 0, -1)  # (n_l,T,ell)
     j_ids = jnp.clip(compact, 0, n - 1)  # (n_l, npr)
-    cj_s = c[j_ids[:, None, :, None], s_ids[:, :, None, :]]  # (n_l,T,npr,ell)
+    cj_s = jnp.moveaxis(c[j_ids[None, :, None, :], s_t[..., None]], 0, -1)  # (n_l,T,npr,ell)
     cij = jnp.broadcast_to(c[rows[:, None], j_ids][:, None, :], (n_l, n_chunk, npr))
 
     mask = _set_mask(adj, compact, rows, s_ids, valid_set, n)
@@ -300,10 +310,13 @@ def gather_s_cols(c_rows, c_cols, col_pos, adj, compact, counts, rows, ranks,
     loc = jnp.arange(n_l, dtype=jnp.int32)
 
     s_pos = col_pos[s_ids]  # (n_l,T,ell) positions into the k gathered cols
-    m2 = c_cols[s_ids[..., :, None], s_pos[..., None, :]]  # (n_l,T,ell,ell)
-    ci_s = c_rows[loc[:, None, None], s_ids]  # (n_l,T,ell)
+    # ℓ-major gathers, as in gather_s
+    s_t = jnp.moveaxis(s_ids, -1, 0)  # (ell, n_l, T)
+    p_t = jnp.moveaxis(s_pos, -1, 0)
+    m2 = jnp.moveaxis(c_cols[s_t[:, None], p_t[None, :]], (0, 1), (-2, -1))  # (n_l,T,ell,ell)
+    ci_s = jnp.moveaxis(c_rows[loc[None, :, None], s_t], 0, -1)  # (n_l,T,ell)
     j_ids = jnp.clip(compact, 0, n - 1)  # (n_l, npr)
-    cj_s = c_cols[j_ids[:, None, :, None], s_pos[:, :, None, :]]  # (n_l,T,npr,ell)
+    cj_s = jnp.moveaxis(c_cols[j_ids[None, :, None, :], p_t[..., None]], 0, -1)  # (n_l,T,npr,ell)
     cij = jnp.broadcast_to(c_rows[loc[:, None], j_ids][:, None, :], (n_l, n_chunk, npr))
 
     mask = _set_mask(adj, compact, rows, s_ids, valid_set, n)
@@ -325,11 +338,11 @@ def ci_sweep(m2, ci_s, cj_s, cij, mask, tau, *, ell: int,
         g = 1.0 / jnp.maximum(m2, 1e-8)  # scalar "inverse"
     else:
         g = _inv_spd(m2, jitter)
-    u_i = jnp.einsum("ntab,ntb->nta", g, ci_s)
-    var_i = 1.0 - jnp.einsum("nta,nta->nt", ci_s, u_i)
-    num = cij - jnp.einsum("ntpl,ntl->ntp", cj_s, u_i)
-    gw = jnp.einsum("ntab,ntpb->ntpa", g, cj_s)
-    var_j = 1.0 - jnp.einsum("ntpa,ntpa->ntp", cj_s, gw)
+    u_i = jnp.einsum("ntab,ntb->nta", g, ci_s, precision=_HI)
+    var_i = 1.0 - jnp.einsum("nta,nta->nt", ci_s, u_i, precision=_HI)
+    num = cij - jnp.einsum("ntpl,ntl->ntp", cj_s, u_i, precision=_HI)
+    gw = jnp.einsum("ntab,ntpb->ntpa", g, cj_s, precision=_HI)
+    var_j = 1.0 - jnp.einsum("ntpa,ntpa->ntp", cj_s, gw, precision=_HI)
     rho = num / jnp.sqrt(jnp.maximum(var_i[..., None] * var_j, 1e-20))
     indep = fisher_z(rho) <= tau  # (n_l,T,npr)
     return indep & mask
@@ -474,11 +487,11 @@ def chunk_e(c, adj, sep, compact, counts, t0, tau, *, ell: int, n_chunk: int, n_
         g = _inv_spd(m2)
     ci_s = c[rows[:, None, None, None], s_ids]  # (n,npr,T,ell)
     cj_s = c[j_ids[:, :, None, None], s_ids]
-    u_i = jnp.einsum("nptab,nptb->npta", g, ci_s)
-    var_i = 1.0 - jnp.einsum("npta,npta->npt", ci_s, u_i)
-    gw = jnp.einsum("nptab,nptb->npta", g, cj_s)
-    var_j = 1.0 - jnp.einsum("npta,npta->npt", cj_s, gw)
-    num = c[rows[:, None], j_ids][:, :, None] - jnp.einsum("npta,npta->npt", cj_s, u_i)
+    u_i = jnp.einsum("nptab,nptb->npta", g, ci_s, precision=_HI)
+    var_i = 1.0 - jnp.einsum("npta,npta->npt", ci_s, u_i, precision=_HI)
+    gw = jnp.einsum("nptab,nptb->npta", g, cj_s, precision=_HI)
+    var_j = 1.0 - jnp.einsum("npta,npta->npt", cj_s, gw, precision=_HI)
+    num = c[rows[:, None], j_ids][:, :, None] - jnp.einsum("npta,npta->npt", cj_s, u_i, precision=_HI)
     rho = num / jnp.sqrt(jnp.maximum(var_i * var_j, 1e-20))
     indep = fisher_z(rho) <= tau  # (n,npr,T)
 
@@ -772,8 +785,9 @@ def _check_rank_capacity(total: int, n_chunk: int, ell: int):
             f"rank capacity of {_rank_dtype().dtype.name}: the commit-key "
             f"capacity is {imax // 2} (keys are rank*2+bit vs the {imax} "
             "sentinel); "
-            "enable jax_enable_x64 (the pc_run launcher does) for int64 "
-            "ranks, or cap max_level"
+            "rerun with JAX_ENABLE_X64=1 and engine='S' for int64 ranks "
+            "(the Pallas kernels do not compile for the TPU under x64), "
+            "or cap max_level"
         )
     while n_chunk > 1 and total + n_chunk > imax:
         n_chunk //= 2

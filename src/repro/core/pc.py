@@ -180,7 +180,11 @@ def _pc_run_host_loop(stats, test, *, engine, lmax, sepset_depth,
         ell += 1
 
     with tracer.span("orient") as sp:
-        cpdag = cpdag_from_skeleton(adj, sep) if orient else adj
+        if orient:
+            max_deg = int(jax.device_get(jnp.max(jnp.sum(adj, axis=1))))
+            cpdag = cpdag_from_skeleton(adj, sep, n_prime=min(n, L.bucket_npr(max(max_deg, 1))))
+        else:
+            cpdag = adj
         sp.sync(cpdag)
 
     return PCRun(
@@ -341,6 +345,7 @@ def pc(
             )
         return _pc_discrete(x, t, engine=engine, max_level=max_level,
                             validate=validate, **kw)
+    x = x.astype(jnp.float32)  # f32 on the device even when x64 is on
     if validate:
         V.validate_samples(x, max_level=max_level)
     if corr not in ("auto", "kernel", "jnp"):

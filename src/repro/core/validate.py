@@ -12,6 +12,9 @@ with an actionable message, raised BEFORE any device dispatch:
 
   * :class:`NonFiniteDataError`     — NaN/Inf in samples or C
   * :class:`ConstantColumnError`    — zero-variance column (corr undefined)
+  * :class:`SampleScaleError`       — a column whose centred sum of squares
+                                      overflows f32 (the correlation would
+                                      read it as all-zero)
   * :class:`RankDeficientError`     — too few samples for the requested
                                       test depth (m ≤ max_level + 3), or
                                       m < n in strict mode (sample
@@ -50,6 +53,10 @@ class NonFiniteDataError(ValidationError):
 
 class ConstantColumnError(ValidationError):
     code = "constant_column"
+
+
+class SampleScaleError(ValidationError):
+    code = "sample_scale"
 
 
 class RankDeficientError(ValidationError):
@@ -138,6 +145,17 @@ def validate_samples(x, max_level: int | None = None,
             "silently reported it as 0 (fabricating independence). Drop the "
             "constant columns (np.delete(x, cols, axis=1)) or add measurement "
             "noise before calling pc()."
+        )
+    xc = x.astype(np.float64) - x.mean(axis=0, dtype=np.float64)
+    ss = np.einsum("ij,ij->j", xc, xc)
+    big = np.flatnonzero(ss > np.finfo(np.float32).max)
+    if big.size:
+        raise SampleScaleError(
+            f"{big.size} column(s) (first: {int(big[0])}) have a centred sum "
+            f"of squares of {ss[big[0]]:.3g}, past the float32 range the "
+            "device standardises in: the correlation would treat them as "
+            "zero-variance. Standardise the columns (x - mean) / std on the "
+            "host before calling pc()."
         )
     _check_m(m, n, max_level, strict_rank)
     return m, n

@@ -41,13 +41,23 @@ def sample_gaussian_dag(
     seed: int = 0,
     noise_std: float = 1.0,
 ):
-    """Returns (x: (m, n) samples, dag). Topological order = variable order."""
+    """Returns (x: (m, n) float64 samples, dag). Topological order =
+    variable order.
+
+    Each column is standardised (zero mean, unit variance) in float64, as
+    expression panels are. The raw variances grow down the topological
+    order (|x| ≈ 2e19 at the paper's DREAM5 size, n=1643), past the float32
+    range the engines compute the correlation in; correlation is
+    scale-invariant, so standardising changes nothing else.
+    """
     rng = np.random.default_rng(seed)
     dag = random_dag(n, density, rng)
     noise = rng.normal(0.0, noise_std, size=(m, n))
     x = np.zeros((m, n))
     for i in range(n):
         x[:, i] = noise[:, i] + x[:, : i] @ dag.weights[i, :i]
+    x -= x.mean(axis=0)
+    x /= x.std(axis=0)
     return x, dag
 
 

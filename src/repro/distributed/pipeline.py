@@ -16,8 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def pipeline_apply(stage_fn, stage_params, xs, mesh, axis: str = "pipe"):
@@ -67,7 +66,12 @@ def pipeline_apply(stage_fn, stage_params, xs, mesh, axis: str = "pipe"):
         jax.tree.map(lambda _: P(axis), stage_params),
         P(),
     )
-    fn = shard_map(runner, mesh=mesh, in_specs=in_specs, out_specs=P(), check_rep=False)
+    # the runner places everything by hand: run it on the mesh's devices
+    # with Auto axes, so the output carries no Explicit sharding type that a
+    # caller's grad outside any mesh context could not seed
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    fn = jax.shard_map(runner, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
     return fn(stage_params, xs)
 
 

@@ -5,7 +5,7 @@ neighbour slots p of the row against the SAME conditioning set:
 
     num   = C_ij − C(j,S)·u_i
     var_j = 1 − C(j,S)·G·C(j,S)
-    indep = |atanh(num/√(var_i·var_j))| ≤ τ   ∧ mask
+    indep = Z(num/√(var_i·var_j)) ≤ τ   ∧ mask     (Z = cit.fisher_z)
 
 Fusing the quadratic form with the Fisher-z threshold keeps every
 intermediate in VREGs; nothing but the final bit per (set, slot) is written
@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.cit import fisher_z
 from .backend import resolve_interpret
 
 
@@ -41,9 +42,9 @@ def _cisweep_kernel(
             for j in range(i + 1, ell):
                 var_j = var_j - 2.0 * w[i] * w[j] * g[i][j]
         rho = num * jax.lax.rsqrt(jnp.maximum(var_i * var_j, 1e-20))
-        rho = jnp.clip(rho, -0.9999999, 0.9999999)
-        indep = jnp.abs(jnp.arctanh(rho)) <= tau
-        out_ref[p] = (indep & (mask_ref[p] > 0)).astype(jnp.uint8)
+        # widen the uint8 mask first: the chip has no 8-bit compare
+        indep = (fisher_z(rho) <= tau) & (mask_ref[p].astype(jnp.int32) > 0)
+        out_ref[p] = indep.astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("ell", "bs", "bp", "interpret"))

@@ -25,8 +25,11 @@ def _corr_kernel(x1_ref, x2_ref, o_ref, acc_ref, *, inv_m: float, k_steps: int):
 
     a = x1_ref[...]  # (bm, bi) slice of standardized samples
     b = x2_ref[...]  # (bm, bj)
+    # fp32 contraction: Mosaic's default rounds the operands to bf16, which
+    # put C 5.5e-4 away from a float64 C on a v5e at n=1643, m=850
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)

@@ -1,4 +1,4 @@
-"""Level-0 kernel (paper Alg. 3): adjacency = |atanh(C)| > τ, elementwise.
+"""Level-0 kernel (paper Alg. 3): adjacency = Z(C) > τ, elementwise.
 
 One fused pass over VMEM tiles of C; the diagonal is masked with a 2-D iota
 against the global tile offsets (no host-side eye matrix).
@@ -12,13 +12,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.cit import fisher_z
 from .backend import resolve_interpret
 
 
 def _level0_kernel(tau_ref, c_ref, o_ref, *, bi: int, bj: int):
     tau = tau_ref[0]
-    c = jnp.clip(c_ref[...], -0.9999999, 0.9999999)
-    z = jnp.abs(jnp.arctanh(c))
+    z = fisher_z(c_ref[...])
     ri = pl.program_id(0) * bi + jax.lax.broadcasted_iota(jnp.int32, (bi, bj), 0)
     cj = pl.program_id(1) * bj + jax.lax.broadcasted_iota(jnp.int32, (bi, bj), 1)
     o_ref[...] = ((z > tau) & (ri != cj)).astype(jnp.uint8)

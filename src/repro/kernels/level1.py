@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.cit import fisher_z
 from .backend import resolve_interpret
 
 _BIG = 2**30  # python int: jnp consts must not be captured by kernels
@@ -42,35 +43,38 @@ def _level1_kernel(
     num = cij[:, :, None] - cik[:, None, :] * cjk[None, :, :]
     den2 = (1.0 - cik * cik)[:, None, :] * (1.0 - cjk * cjk)[None, :, :]
     rho = num * jax.lax.rsqrt(jnp.maximum(den2, 1e-20))
-    rho = jnp.clip(rho, -0.9999999, 0.9999999)
-    indep = jnp.abs(jnp.arctanh(rho)) <= tau  # (bi, bj, bk)
+    indep = fisher_z(rho) <= tau  # (bi, bj, bk)
 
     # masks: k ≠ i, k ≠ j; edge alive. `found` uses k ∈ adj(i) ∪ adj(j) (the
     # union of both endpoints' candidate pools — what decides removal);
     # `kwin` is restricted to the ROW-LOCAL pool k ∈ adj(i) so the host
     # commit can rank it inside row i's compacted neighbour list and replay
     # the chunked S engine's deterministic (rank, endpoint-order) winner.
-    k_own = (adj_ik_ref[...] > 0)[:, None, :]
-    kmask = k_own | (adj_jk_ref[...] > 0)[None, :, :]
+    # Every reshape and compare runs on int32: Mosaic cannot relayout bool
+    # vectors into 3-D and the chip has no 8-bit compare.
+    a_ik = adj_ik_ref[...].astype(jnp.int32)[:, None, :]  # (bi, 1, bk)
+    a_jk = adj_jk_ref[...].astype(jnp.int32)[None, :, :]  # (1, bj, bk)
+    a_ij = adj_ij_ref[...].astype(jnp.int32)[:, :, None]  # (bi, bj, 1)
+    cube = (bi, bj, bk)
     gi = pl.program_id(0) * bi + jax.lax.broadcasted_iota(jnp.int32, (bi, bk), 0)
     gj = pl.program_id(1) * bj + jax.lax.broadcasted_iota(jnp.int32, (bj, bk), 0)
-    gk_i = pl.program_id(2) * bk + jax.lax.broadcasted_iota(jnp.int32, (bi, bk), 1)
-    gk_j = pl.program_id(2) * bk + jax.lax.broadcasted_iota(jnp.int32, (bj, bk), 1)
-    neq = (gk_i != gi)[:, None, :] & (gk_j != gj)[None, :, :]
-    kmask &= neq
-    alive = (adj_ij_ref[...] > 0)
-
-    sep = indep & kmask & alive[:, :, None]
-    found_acc[...] |= jnp.any(sep, axis=-1).astype(jnp.uint8) > 0
-    sep_own = indep & k_own & neq & alive[:, :, None]
-    gk3 = pl.program_id(2) * bk + jax.lax.broadcasted_iota(jnp.int32, (bi, bj, bk), 2)
+    gk = pl.program_id(2) * bk + jax.lax.broadcasted_iota(jnp.int32, (bj, bk), 1)
+    gi3 = jnp.broadcast_to(gi[:, None, :], cube)
+    gk3 = jnp.broadcast_to(gk[None, :, :], cube)
+    neq = (gk3 != gi3) & (gk3 != jnp.broadcast_to(gj[None, :, :], cube))
+    live = indep & neq & (jnp.broadcast_to(a_ij, cube) > 0)
+    k_own = jnp.broadcast_to(a_ik, cube) > 0
+    sep = live & (k_own | (jnp.broadcast_to(a_jk, cube) > 0))
+    found_acc[...] = jnp.maximum(
+        found_acc[...], jnp.max(jnp.where(sep, 1, 0), axis=-1)
+    )
     kmin_acc[...] = jnp.minimum(
-        kmin_acc[...], jnp.min(jnp.where(sep_own, gk3, _BIG), axis=-1)
+        kmin_acc[...], jnp.min(jnp.where(live & k_own, gk3, _BIG), axis=-1)
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _done():
-        rem_ref[...] = found_acc[...].astype(jnp.uint8)
+        rem_ref[...] = found_acc[...]
         kwin_ref[...] = kmin_acc[...]
 
 
@@ -81,7 +85,7 @@ def level1_dense_kernel(
 ):
     """c: (n,n) fp32, adj: (n,n) uint8 (G′ snapshot), n % lcm(bi,bj,bk) == 0.
 
-    Returns (removed (n,n) uint8 — separator exists in adj(i) ∪ adj(j);
+    Returns (removed (n,n) int32 0/1 — separator exists in adj(i) ∪ adj(j);
     kwin (n,n) int32 — min separating k ∈ adj(i) \\ {j}, else 2^30).
     interpret=None auto-detects the backend (interpret mode off-TPU)."""
     interpret = resolve_interpret(interpret)
@@ -109,11 +113,11 @@ def level1_dense_kernel(
             pl.BlockSpec((bi, bj), lambda i, j, k: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, n), jnp.uint8),
+            jax.ShapeDtypeStruct((n, n), jnp.int32),
             jax.ShapeDtypeStruct((n, n), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bi, bj), jnp.bool_),
+            pltpu.VMEM((bi, bj), jnp.int32),
             pltpu.VMEM((bi, bj), jnp.int32),
         ],
         interpret=interpret,

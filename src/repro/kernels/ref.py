@@ -8,6 +8,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..core.cit import fisher_z
+
 _BIG = jnp.int32(2**30)
 
 
@@ -23,8 +25,7 @@ def corr_ref(x: jax.Array) -> jax.Array:
 
 
 def level0_ref(c: jax.Array, tau: float) -> jax.Array:
-    rho = jnp.clip(c, -0.9999999, 0.9999999)
-    keep = jnp.abs(jnp.arctanh(rho)) > tau
+    keep = fisher_z(c) > tau
     return keep & ~jnp.eye(c.shape[0], dtype=bool)
 
 
@@ -46,8 +47,7 @@ def level1_dense_ref(c: jax.Array, adj: jax.Array, tau: float):
     den = jnp.sqrt(
         jnp.maximum((1.0 - cik * cik) * (1.0 - cjk * cjk), 1e-20)
     )
-    rho = jnp.clip(num / den, -0.9999999, 0.9999999)
-    indep = jnp.abs(jnp.arctanh(rho)) <= tau  # (i,j,k)
+    indep = fisher_z(num / den) <= tau  # (i,j,k)
 
     ks = jnp.arange(n)
     k_own = adj[:, None, :]  # k nbr of i (G')
@@ -79,5 +79,4 @@ def cisweep_ref(g, u_i, var_i, cj_s, cij, mask, tau: float):
     gw = jnp.einsum("bxy,bpy->bpx", g, cj_s)
     var_j = 1.0 - jnp.einsum("bpx,bpx->bp", cj_s, gw)
     rho = num / jnp.sqrt(jnp.maximum(var_i[:, None] * var_j, 1e-20))
-    rho = jnp.clip(rho, -0.9999999, 0.9999999)
-    return (jnp.abs(jnp.arctanh(rho)) <= tau) & mask
+    return (fisher_z(rho) <= tau) & mask

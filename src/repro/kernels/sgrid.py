@@ -6,9 +6,12 @@ per-(row, slot) winners in XLA — one host dispatch (and one HBM round-trip
 of ``sep_found``) per chunk. This kernel folds the whole rank loop into ONE
 ``pallas_call``:
 
-  * grid = (row-lane groups, rank steps): rows live on the 128 vector
-    lanes, ranks stream through the sublane axis 8 at a time; the rank-step
-    dim is innermost, so consecutive steps revisit the same output block;
+  * grid = (row-lane groups, neighbour-slot blocks, rank steps): rows live
+    on the 128 vector lanes, ranks stream through the sublane axis 8 at a
+    time; the rank-step dim is innermost, so consecutive steps revisit the
+    same output block. Slots come ``bp`` at a time (:func:`slot_block`):
+    the body unrolls over them, so a whole n′ ≈ 1.6k row would blow both
+    the compile time and the 16 MiB scoped VMEM;
   * the winner arrays accumulate ACROSS grid steps in the output blocks
     (index maps independent of the rank step — the canonical Pallas
     reduction pattern): ``t_win`` as the min separating local rank and
@@ -41,11 +44,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.cit import fisher_z
 from .backend import resolve_interpret
 
 #: "no separating set found" marker for the launch-local int32 rank — same
 #: ≥ 2^30 convention as the dense ℓ=1 kernel's kwin.
 SENTINEL = 2**30
+
+
+def slot_block(npr: int, ell: int) -> int:
+    """Neighbour slots per grid step: all of them when few, else a power of
+    two that keeps the (slots, ℓ, 8, 128) fp32 block ≤ 512 KiB (≥ 8)."""
+    cap = 1 << (max(8, 128 // ell).bit_length() - 1)
+    return npr if npr <= cap else cap
 
 
 def _inverse_tiles(m2_ref, *, ell: int, jitter: float):
@@ -107,10 +118,10 @@ def _inverse_tiles(m2_ref, *, ell: int, jitter: float):
 
 def _sgrid_kernel(
     tau_ref, m2_ref, ci_ref, cjs_ref, cij_ref, mask_ref, sid_ref,
-    twin_ref, swin_ref, *, ell: int, npr: int, tb: int,
+    twin_ref, swin_ref, *, ell: int, bp: int, tb: int,
     jitter: float,
 ):
-    step = pl.program_id(1)  # rank step (innermost → sequential revisits)
+    step = pl.program_id(2)  # rank step (innermost → sequential revisits)
 
     @pl.when(step == 0)
     def _():
@@ -132,7 +143,7 @@ def _sgrid_kernel(
     # launch-local ranks of this step, broadcast over rows (lanes)
     t_loc = step * tb + jax.lax.broadcasted_iota(jnp.int32, (tb, 128), 0)
 
-    for p in range(npr):
+    for p in range(bp):
         w = [cjs_ref[p, i] for i in range(ell)]
         num = cij_ref[p]
         var_j = 1.0
@@ -142,8 +153,8 @@ def _sgrid_kernel(
             for j in range(i + 1, ell):
                 var_j = var_j - 2.0 * w[i] * w[j] * g[i][j]
         rho = num * jax.lax.rsqrt(jnp.maximum(var_i * var_j, 1e-20))
-        rho = jnp.clip(rho, -0.9999999, 0.9999999)
-        indep = (jnp.abs(jnp.arctanh(rho)) <= tau) & (mask_ref[p] > 0)
+        # widen the uint8 mask first: the chip has no 8-bit compare
+        indep = (fisher_z(rho) <= tau) & (mask_ref[p].astype(jnp.int32) > 0)
 
         key = jnp.where(indep, t_loc, SENTINEL)          # (tb, 128)
         kmin = jnp.min(key, axis=0, keepdims=True)       # (1, 128)
@@ -181,29 +192,36 @@ def sgrid_kernel(
     interpret = resolve_interpret(interpret)
     t_total, n_lanes = cij.shape[-2:]
     lane = 128
-    grid = (n_lanes // lane, t_total // tb)
+    bp = slot_block(npr, ell)
+    npr_pad = -(-npr // bp) * bp
+    if npr_pad != npr:  # padded slots are masked out: they never separate
+        def pad(x):
+            return jnp.pad(x, [(0, npr_pad - npr)] + [(0, 0)] * (x.ndim - 1))
+        cj_s, cij, mask = pad(cj_s), pad(cij), pad(mask)
+    grid = (n_lanes // lane, npr_pad // bp, t_total // tb)
     tau_arr = jnp.asarray(tau, jnp.float32).reshape(1)
-    return pl.pallas_call(
+    twin, swin = pl.pallas_call(
         functools.partial(
-            _sgrid_kernel, ell=ell, npr=npr, tb=tb, jitter=jitter
+            _sgrid_kernel, ell=ell, bp=bp, tb=tb, jitter=jitter
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((ell, ell, tb, lane), lambda g, s: (0, 0, s, g)),
-            pl.BlockSpec((ell, tb, lane), lambda g, s: (0, s, g)),
-            pl.BlockSpec((npr, ell, tb, lane), lambda g, s: (0, 0, s, g)),
-            pl.BlockSpec((npr, tb, lane), lambda g, s: (0, s, g)),
-            pl.BlockSpec((npr, tb, lane), lambda g, s: (0, s, g)),
-            pl.BlockSpec((ell, tb, lane), lambda g, s: (0, s, g)),
+            pl.BlockSpec((ell, ell, tb, lane), lambda g, p, s: (0, 0, s, g)),
+            pl.BlockSpec((ell, tb, lane), lambda g, p, s: (0, s, g)),
+            pl.BlockSpec((bp, ell, tb, lane), lambda g, p, s: (p, 0, s, g)),
+            pl.BlockSpec((bp, tb, lane), lambda g, p, s: (p, s, g)),
+            pl.BlockSpec((bp, tb, lane), lambda g, p, s: (p, s, g)),
+            pl.BlockSpec((ell, tb, lane), lambda g, p, s: (0, s, g)),
         ],
         out_specs=[
-            pl.BlockSpec((npr, lane), lambda g, s: (0, g)),
-            pl.BlockSpec((npr * ell, lane), lambda g, s: (0, g)),
+            pl.BlockSpec((bp, lane), lambda g, p, s: (p, g)),
+            pl.BlockSpec((bp * ell, lane), lambda g, p, s: (p, g)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((npr, n_lanes), jnp.int32),
-            jax.ShapeDtypeStruct((npr * ell, n_lanes), jnp.int32),
+            jax.ShapeDtypeStruct((npr_pad, n_lanes), jnp.int32),
+            jax.ShapeDtypeStruct((npr_pad * ell, n_lanes), jnp.int32),
         ],
         interpret=interpret,
     )(tau_arr, m2, ci_s, cj_s, cij, mask, s_ids)
+    return twin[:npr], swin[: npr * ell]

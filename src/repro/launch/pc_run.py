@@ -35,6 +35,11 @@ too, see README "Running the sharded paths without a TPU"):
 ``--mesh K`` builds a flat K-device mesh; ``--shard-batch`` shards the
 leading B axis of --batch/--bootstrap over it (same compiled program per
 device, B/K local graphs each).
+
+Ranks are int32 and samples f32. A level whose C(n′, ℓ) overflows the int32
+commit keys stops with an error; rerun with ``JAX_ENABLE_X64=1 --engine S``
+to carry int64 ranks (which the TPU emulates; the Pallas kernels do not
+compile for the TPU under x64).
 """
 from __future__ import annotations
 
@@ -45,9 +50,8 @@ import numpy as np
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import MonotonicClock
-
-jax.config.update("jax_enable_x64", True)  # C(n', l) ranks overflow int32
 
 _CLK = MonotonicClock()  # the obs timing seam — no raw perf_counter (RPR003)
 
@@ -217,6 +221,7 @@ def main():
                     help="enable obs and write the run's trace spans to "
                          "PATH (JSONL; docs/observability.md)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.journal:
         from repro import obs

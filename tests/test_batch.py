@@ -309,7 +309,9 @@ def test_bootstrap_corr_validates_and_shapes():
 @settings(max_examples=15, deadline=None)
 def test_property_cpdag_matches_serial_oracle(n, density, seed):
     """cpdag_from_skeleton == cpdag_np on random sparse skeletons+sepsets
-    (generated consistently via the d-separation oracle on random DAGs)."""
+    (generated consistently via the d-separation oracle on random DAGs), at
+    the default neighbour-list width (n) and at the tightest one (the max
+    degree, as the host drivers bound it)."""
     _, dag = sample_gaussian_dag(n=n, m=10, density=density, seed=seed)
     adj_o, sep_o = oracle_pc_stable(dag)
     cp_ref = cpdag_np(adj_o, sep_o)
@@ -317,8 +319,11 @@ def test_property_cpdag_matches_serial_oracle(n, density, seed):
     for (i, j), s in sep_o.items():
         sep[i, j, : len(s)] = s
         sep[j, i, : len(s)] = s
-    cp_jax = np.asarray(cpdag_from_skeleton(jnp.asarray(adj_o), jnp.asarray(sep)))
-    np.testing.assert_array_equal(cp_jax, cp_ref)
+    tight = max(int(adj_o.sum(axis=1).max()), 1)
+    for n_prime in (None, tight):
+        cp_jax = np.asarray(cpdag_from_skeleton(
+            jnp.asarray(adj_o), jnp.asarray(sep), n_prime=n_prime))
+        np.testing.assert_array_equal(cp_jax, cp_ref)
 
 
 def test_sepset_membership_matches_bruteforce():

@@ -24,6 +24,7 @@ from repro.core.combinadics import (
 )
 from repro.core.compact import compact_rows, compact_rows_np
 from repro.core.orient import cpdag_from_skeleton, cpdag_np
+from repro.configs.cupc_datasets import CUPC_DATASETS
 from repro.core.stable_ref import pc_stable_skeleton
 from repro.data.synthetic_dag import (
     d_separated,
@@ -305,6 +306,34 @@ def test_constant_column_rejected_not_silent():
     x[:, 4] = 3.25
     with pytest.raises(ConstantColumnError, match=r"\[4\]"):
         pc(x, alpha=0.01, engine="S")
+
+
+def test_f32_overflowing_column_rejected():
+    """A column whose centred sum of squares passes the float32 range (raw
+    |x| ≈ 2e19 over 850 samples does) would standardise to zeros on the
+    device: reject it at the door."""
+    from repro.core.validate import SampleScaleError
+
+    x, _ = sample_gaussian_dag(n=10, m=500, density=0.2, seed=0)
+    x = np.asarray(x).copy()
+    x[:, 7] *= 1e19
+    with pytest.raises(SampleScaleError, match=r"first: 7"):
+        pc(x, alpha=0.01, engine="S")
+    x[:, 7] /= 1e19
+    pc(x, alpha=0.01, engine="S")  # in range: runs
+
+
+@pytest.mark.parametrize("name", sorted(CUPC_DATASETS))
+def test_published_dataset_samples_pass_admission(name):
+    """What ``pc_run --dataset <name>`` (and the benchmarks and pc_serve)
+    feed pc() passes admission at the published size: the generator's
+    columns are standardised, so no f32 sum of squares overflows."""
+    from repro.core.validate import validate_samples
+
+    ds = CUPC_DATASETS[name]
+    x, _ = sample_gaussian_dag(n=ds.n, m=ds.m, density=ds.density, seed=0)
+    assert validate_samples(x) == (ds.m, ds.n)
+    np.testing.assert_allclose(x.std(axis=0), 1.0, rtol=1e-12)
 
 
 def test_nonfinite_inputs_rejected_with_typed_errors():

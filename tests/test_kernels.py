@@ -157,3 +157,38 @@ def test_gsq_known_value():
     g2 = float(gsq.gsq_ref(jnp.asarray(codes[:, None], jnp.int32), r=2, q=1)[0])
     want = chi2_contingency(tab, correction=False, lambda_="log-likelihood").statistic
     assert g2 == pytest.approx(want, rel=1e-5)
+
+
+# ------------------------------------------------ S-grid over slot blocks
+@pytest.mark.parametrize("ell", [2, 3])
+def test_sgrid_slot_blocks_match_jnp_sweep(ell):
+    """A row wider than one slot block (kernels/sgrid.slot_block) runs as
+    several blocks on the grid, with the tail slots padded — the winners
+    must equal the jnp sweep's (levels.ci_sweep) min separating rank."""
+    from repro.core import levels as L
+    from repro.core.cit import correlation_from_samples, threshold
+    from repro.core.compact import compact_rows
+    from repro.kernels import sgrid
+
+    n, m, t = 80, 200, 16
+    x = RNG.normal(size=(m, n))
+    x[:, 1:] += 0.5 * x[:, :-1]
+    c = correlation_from_samples(jnp.asarray(x))
+    adj = ~jnp.eye(n, dtype=bool)
+    compact, counts = compact_rows(adj, n_prime=n)
+    assert sgrid.slot_block(n, ell) < n
+    ranks = jnp.arange(t, dtype=jnp.int32)
+    gathered = L.gather_s(c, adj, compact, counts, jnp.arange(n, dtype=jnp.int32),
+                          ranks, ell=ell, n_max=n)
+    tau = threshold(m, ell, 0.01)
+    t_loc, s_win = ops.ci_shared_grid(*gathered, tau, ell=ell)
+
+    found = np.asarray(L.ci_sweep(*gathered[:5], tau, ell=ell))  # (n, T, n′)
+    first = found.argmax(axis=1)
+    want_t = np.where(found.any(axis=1), first, sgrid.SENTINEL)
+    np.testing.assert_array_equal(np.asarray(t_loc), want_t)
+    s_ids = np.asarray(gathered[5])  # (n, T, ℓ)
+    want_s = np.take_along_axis(s_ids, first[:, :, None].clip(0, t - 1), axis=1)
+    hit = found.any(axis=1)
+    assert hit.any() and not hit.all()
+    np.testing.assert_array_equal(np.asarray(s_win)[hit], want_s[hit])

@@ -153,6 +153,11 @@ def test_shard_c_memory_layout_specs():
             assert st["shard_c"]
             assert st["k_cols"] < n, (st["k_cols"], n)   # O(n·k) gather, k < n
             assert SH.AXIS in st["c_sharding"]
+            # the C the run read: one disjoint row block per device
+            blocks = st["c_row_blocks"]
+            assert len({d for d, _, _ in blocks}) == ndev, blocks
+            assert [(a, b) for _, a, b in sorted(blocks, key=lambda t: t[1])] == [
+                (r, r + n_pad // ndev) for r in range(0, n_pad, n_pad // ndev)]
         print("OK")
     """)
 
