@@ -5,8 +5,9 @@ contracts that the jaxpr and Pallas layers cannot see (they only look at
 what traces; these rules look at what is *written*):
 
   RPR001  host-sync primitive inside a jitted/traced function body
-  RPR002  host-sync seam (device_get / .item() / block_until_ready) in
-          library code without an ALLOWLIST entry naming the seam
+  RPR002  host-sync seam (device_get / .item() / block_until_ready /
+          obs.fetch) in library code without an ALLOWLIST entry naming
+          the seam
   RPR003  ``time.perf_counter`` outside ``src/repro/obs`` — spans/clocks
           are the one timing seam
   RPR004  kernel entry point whose ``interpret`` default is not ``None``
@@ -25,7 +26,9 @@ The seam ALLOWLIST below is the machine-readable registry of every place
 the architecture *intends* a host sync: level-plan barriers (the next
 level's shapes depend on the device's max degree), end-of-run result
 materialisation, checkpoint device→host transfer, elastic re-meshing, and
-the obs layer's ``sp.sync()``. Findings at those keys never surface; a new
+the obs layer's ``sp.sync()`` and ``obs.fetch`` (the counted read the
+single-device ``pc`` path makes all its blocking reads through; a call of
+it is a sync primitive like ``device_get``). Findings at those keys never surface; a new
 sync anywhere else fails CI until it is either removed or added here with
 a justification.
 """
@@ -74,27 +77,27 @@ STATIC_ARGNAME_ALLOWLIST = {
 ALLOWLIST: dict[str, str] = {
     # ---- level-plan barriers: the next level's static shapes (n', chunking)
     # ---- depend on the device-side max degree; one sync per level by design
-    "RPR002 src/repro/core/levels.py::run_level::np.asarray(device_get)":
+    "RPR002 src/repro/core/levels.py::run_level::np.asarray(obs.fetch)":
         "per-level plan barrier: chunk shapes derive from the device max degree",
-    "RPR002 src/repro/core/pc.py::_pc_run_host_loop::device_get":
+    "RPR002 src/repro/core/pc.py::_pc_run_host_loop::obs.fetch":
         "level-ladder barrier: max_deg decides whether another level runs",
     "RPR002 src/repro/core/distributed.py::run_level_sharded::np.asarray(device_get)":
         "sharded per-level plan barrier (same contract as levels.run_level)",
     "RPR002 src/repro/core/distributed.py::pc_distributed::device_get":
         "distributed level-ladder barrier on the gathered max degree",
-    "RPR002 src/repro/core/engines.py::_run_level_dense_l1::device_get":
+    "RPR002 src/repro/core/engines.py::_run_level_dense_l1::obs.fetch":
         "dense-l1 planner reads the max degree to size the compacted commit",
-    "RPR002 src/repro/batch/scan_pc.py::plan_n_prime::device_get":
+    "RPR002 src/repro/batch/scan_pc.py::plan_n_prime::obs.fetch":
         "scan planner: one sync for the exact level-0 degree bound (documented)",
-    "RPR002 src/repro/batch/scan_pc.py::_prep::device_get":
+    "RPR002 src/repro/batch/scan_pc.py::_prep::obs.fetch":
         "discrete scan planner: level-0 degree bound before the traced build",
     "RPR002 src/repro/batch/scan_pc.py::scan_levels_batch::device_get":
         "batch schedule barrier: the shared width is the batch max degree",
     # ---- end-of-run result materialisation: PCRun/ScanResult fields are
     # ---- numpy by contract (the public API boundary)
-    "RPR002 src/repro/core/pc.py::_pc_run_host_loop::np.asarray(device_get)":
+    "RPR002 src/repro/core/pc.py::_pc_run_host_loop::np.asarray(obs.fetch)":
         "PCRun materialisation: public result fields are host numpy by contract",
-    "RPR002 src/repro/core/pc.py::_pc_run_scan::np.asarray(device_get)":
+    "RPR002 src/repro/core/pc.py::_pc_run_scan::np.asarray(obs.fetch)":
         "PCRun materialisation of the traced-scan outputs (API boundary)",
     "RPR002 src/repro/core/distributed.py::pc_distributed::np.asarray(device_get)":
         "PCRun materialisation after the distributed run (API boundary)",
@@ -107,8 +110,14 @@ ALLOWLIST: dict[str, str] = {
         "checkpointing IS the device->host transfer (async save path)",
     "RPR002 src/repro/distributed/elastic.py::remesh::device_get":
         "elastic re-meshing round-trips through host to re-place shards",
-    "RPR002 src/repro/obs/trace.py::span::block_until_ready":
-        "sp.sync(): the ONE sanctioned sync so span timings measure device work",
+    "RPR002 src/repro/obs/trace.py::_block::block_until_ready":
+        "sp.sync(): the sanctioned span sync so span timings measure device work",
+    "RPR002 src/repro/obs/trace.py::fetch::device_get":
+        "obs.fetch(): the counted seam every blocking read of the pc path uses",
+    "RPR002 src/repro/core/cit.py::threshold::obs.fetch":
+        "tau's inverse normal runs as jax ndtri on the device; its scalar comes back",
+    "RPR002 src/repro/core/validate.py::_as_host::np.asarray(obs.fetch)":
+        "admission reads device-resident samples back to check them on host",
 }
 
 
@@ -293,6 +302,8 @@ class _Visitor(ast.NodeVisitor):
             sync = ".item()"
         elif tail == "device_get":
             sync = "device_get"
+        elif dotted == "obs.fetch" or dotted.endswith(".obs.fetch"):
+            sync = "obs.fetch"
         elif tail == "block_until_ready" or (
             isinstance(node.func, ast.Attribute)
             and node.func.attr == "block_until_ready"
@@ -318,8 +329,8 @@ class _Visitor(ast.NodeVisitor):
             detail = sync
             # collapse the idiomatic np.asarray(jax.device_get(x)) pair into
             # one seam key so the allowlist names the materialisation once
-            if sync == "device_get" and self._inside_np_asarray(node):
-                detail = "np.asarray(device_get)"
+            if sync in ("device_get", "obs.fetch") and self._inside_np_asarray(node):
+                detail = f"np.asarray({sync})"
             self._emit(
                 RPR002, node,
                 f"host sync `{sync}` in library code — every seam must be "
@@ -374,14 +385,15 @@ class _Visitor(ast.NodeVisitor):
 
 
 def _annotate_asarray_parents(tree):
-    """Mark device_get calls that sit directly inside np.asarray(...) so the
-    pair collapses to one 'np.asarray(device_get)' seam key."""
+    """Mark device_get / obs.fetch calls that sit directly inside
+    np.asarray(...) so the pair collapses to one 'np.asarray(<sync>)' seam
+    key."""
     for node in ast.walk(tree):
         if isinstance(node, ast.Call) and (
             _dotted(node.func) in ("np.asarray", "numpy.asarray")
         ):
             for arg in node.args:
-                if isinstance(arg, ast.Call) and _tail(arg.func) == "device_get":
+                if isinstance(arg, ast.Call) and _tail(arg.func) in ("device_get", "fetch"):
                     arg._parent_call = node
 
 

@@ -148,7 +148,7 @@ def plan_n_prime(cs, m: int, alpha: float = 0.01, tau0=None) -> int:
         tau0 = threshold(m, 0, alpha)
     tau0 = jnp.broadcast_to(jnp.asarray(tau0, jnp.float32), (cs.shape[0],))
     deg = jax.vmap(lambda c, t: jnp.max(jnp.sum(L.level0(c, t), axis=1)))(cs, tau0)
-    npr = int(jax.device_get(jnp.max(deg)))
+    npr = int(obs.fetch(jnp.max(deg), site="scan.plan_n_prime"))
     n = int(cs.shape[-1])
     return max(1, min(L.bucket_npr(npr), n))
 
@@ -445,7 +445,7 @@ def _prep(c, m, alpha, max_level, sepset_depth, n_prime, taus=None, test=None):
         if discrete:
             test.check_level(max_level)
             adj0 = L.level0_g2(c, float(taus[0]), r=test.r)
-            npr = int(jax.device_get(jnp.max(jnp.sum(adj0, axis=1))))
+            npr = int(obs.fetch(jnp.max(jnp.sum(adj0, axis=1)), site="scan.prep"))
             n_prime = max(1, min(L.bucket_npr(npr), n))
         else:
             n_prime = plan_n_prime(c, m, alpha, tau0=taus[..., 0])

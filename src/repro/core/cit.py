@@ -42,6 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.scipy.special import ndtri
 
+from repro import obs
+
 #: f32 matmuls here run at full f32 precision: the TPU's default rounds
 #: operands to bf16, which moves C and partial correlations by ~1e-3 and
 #: flips tests near τ. On the CPU this changes no bit.
@@ -67,7 +69,9 @@ def fisher_z(rho: jax.Array) -> jax.Array:
 
 def threshold(m: int, ell: int, alpha: float, *,
               insufficient: str = "raise") -> float:
-    """τ = Φ⁻¹(1−α/2)/√(m−ℓ−3)  (Eq. 7). Host-side scalar.
+    """τ = Φ⁻¹(1−α/2)/√(m−ℓ−3)  (Eq. 7), returned as a host float. Φ⁻¹ is
+    ``jax.scipy.special.ndtri``, which runs on the default device; its
+    scalar is read back through ``obs.fetch`` (a counted host sync).
 
     When m − ℓ − 3 ≤ 0 the statistic's variance normaliser is undefined —
     the level cannot be tested at this sample count. ``insufficient``
@@ -99,7 +103,8 @@ def threshold(m: int, ell: int, alpha: float, *,
         if insufficient == "warn":
             warnings.warn(msg, stacklevel=2)
         denom = 1
-    return float(ndtri(1.0 - alpha / 2.0)) / float(denom) ** 0.5
+    z = obs.fetch(ndtri(1.0 - alpha / 2.0), site="cit.threshold")
+    return float(z) / float(denom) ** 0.5
 
 
 def pseudo_inverse(m2: jax.Array) -> jax.Array:

@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 
 from repro import obs
@@ -248,7 +247,9 @@ def _run_level_dense_l1(c, adj, sep, tau):
     M2 gathers, no host loop (the paper's dominant level, Fig. 6)."""
     from repro.kernels.ops import level1_dense
 
-    npr = int(jax.device_get(jnp.max(jnp.sum(adj, axis=1))))
+    with obs.current().span("degree", level=1):
+        npr = int(obs.fetch(jnp.max(jnp.sum(adj, axis=1)),
+                            site="engines.dense_l1_degree"))
     if npr - 1 < 1:
         return adj, sep, {"skipped": True, "chunks": 0, "dispatches": 0,
                           "npr": npr, "engine": "L1-dense"}

@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from .cit import fisher_z
 from .combinadics import binom_table
 
@@ -893,15 +895,17 @@ def run_level(
     # adj (not c) owns the variable count: the c slot may carry a non-array
     # sufficient-statistics pytree (e.g. cit.DiscreteStats for chunk_g2)
     n = adj.shape[0]
-    counts_host = np.asarray(jax.device_get(jnp.sum(adj, axis=1)))
-    npr = int(counts_host.max(initial=0))
-    if npr - 1 < ell:
-        return adj, sep, {"skipped": True, "chunks": 0, "dispatches": 0,
-                          "npr": npr, "engine": engine}
-    npr_b, n_chunk, total = plan_level(
-        npr, ell, n, engine=engine, cell_budget=cell_budget, bucket=bucket, n_cols=n
-    )
-    compact, counts = compact_rows(adj, n_prime=npr_b)
+    tracer = obs.current()
+    with tracer.span("plan", level=ell):
+        counts_host = np.asarray(obs.fetch(jnp.sum(adj, axis=1), site="levels.plan"))
+        npr = int(counts_host.max(initial=0))
+        if npr - 1 < ell:
+            return adj, sep, {"skipped": True, "chunks": 0, "dispatches": 0,
+                              "npr": npr, "engine": engine}
+        npr_b, n_chunk, total = plan_level(
+            npr, ell, n, engine=engine, cell_budget=cell_budget, bucket=bucket, n_cols=n
+        )
+        compact, counts = compact_rows(adj, n_prime=npr_b)
     depth = max(1, pipeline_depth)
     pipelined = depth > 1 and engine.upper() == "S" and chunk_fn_s is None
 
@@ -909,22 +913,24 @@ def run_level(
     if pipelined:
         pending: deque = deque()
         for t0 in range(0, total, n_chunk):
-            pending.append(chunk_s_tests(
-                c, adj, compact, counts, jnp.asarray(t0, _rank_dtype()), tau,
-                ell=ell, n_chunk=n_chunk, n_max=npr_b,
-            ))
-            chunks += 1
-            if len(pending) >= depth:
-                adj, sep = chunk_s_commit(adj, sep, compact, *pending.popleft(), ell=ell)
+            with tracer.span("chunk", t0=t0):
+                pending.append(chunk_s_tests(
+                    c, adj, compact, counts, jnp.asarray(t0, _rank_dtype()), tau,
+                    ell=ell, n_chunk=n_chunk, n_max=npr_b,
+                ))
+                chunks += 1
+                if len(pending) >= depth:
+                    adj, sep = chunk_s_commit(adj, sep, compact, *pending.popleft(), ell=ell)
         while pending:
             adj, sep = chunk_s_commit(adj, sep, compact, *pending.popleft(), ell=ell)
     else:
         fn = (chunk_fn_s or chunk_s) if engine.upper() == "S" else (chunk_fn_e or chunk_e)
         for t0 in range(0, total, n_chunk):
-            adj, sep = fn(
-                c, adj, sep, compact, counts, jnp.asarray(t0, _rank_dtype()), tau,
-                ell=ell, n_chunk=n_chunk, n_max=npr_b,
-            )
+            with tracer.span("chunk", t0=t0):
+                adj, sep = fn(
+                    c, adj, sep, compact, counts, jnp.asarray(t0, _rank_dtype()), tau,
+                    ell=ell, n_chunk=n_chunk, n_max=npr_b,
+                )
             chunks += 1
     return adj, sep, {
         "skipped": False, "chunks": chunks, "npr": npr, "npr_bucket": npr_b,

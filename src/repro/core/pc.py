@@ -44,6 +44,7 @@ class PCRun:
     levels_run: int
     level_stats: list = field(default_factory=list)
     timings_s: dict = field(default_factory=dict)
+    counts: dict = field(default_factory=dict)  # tracer counts, e.g. host_syncs
 
     def sepset_dict(self) -> dict:
         """{(i, j) i<j → tuple of separator ids} for removed edges with a
@@ -107,29 +108,51 @@ def pc_from_corr(
             "pc(x, test=...) instead"
         )
     tracer = obs.run_tracer("pc_from_corr")
-    with tracer.span("total", engine=str(engine)):
+    with tracer.activate(), tracer.span("total", engine=str(engine)):
         if validate:
-            V.validate_corr(c, m, max_level=max_level)
-        c = jnp.asarray(c, jnp.float32)
-        lmax = min(max_level if max_level is not None else MAX_LEVEL,
-                   sepset_depth)
+            with tracer.span("validate"):
+                V.validate_corr(c, m, max_level=max_level)
+        with tracer.span("upload"):
+            c = jnp.asarray(c, jnp.float32)
+        run = _pc_gaussian(
+            c, m, alpha, test, engine=engine, max_level=max_level,
+            sepset_depth=sepset_depth, cell_budget=cell_budget,
+            orient=orient, chunk_fn_s=chunk_fn_s, chunk_fn_e=chunk_fn_e,
+            bucket=bucket, pipeline_depth=pipeline_depth, tracer=tracer,
+        )
+    return _finish(run, tracer, "pc_from_corr", engine)
 
-        if E.is_whole_run(engine):
-            run = _pc_run_scan(
-                c, m, alpha=alpha, max_level=max_level,
-                sepset_depth=sepset_depth, cell_budget=cell_budget,
-                orient=orient, tracer=tracer,
-            )
-        else:
-            run = _pc_run_host_loop(
-                c, test, engine=engine, lmax=lmax,
-                sepset_depth=sepset_depth, cell_budget=cell_budget,
-                orient=orient, bucket=bucket, chunk_fn_s=chunk_fn_s,
-                chunk_fn_e=chunk_fn_e, pipeline_depth=pipeline_depth,
-                tracer=tracer,
-            )
+
+def _pc_gaussian(c, m, alpha, test, *, tracer, engine="auto",
+                 max_level=None, sepset_depth: int = 8,
+                 cell_budget: int = E.DEFAULT_CELL_BUDGET,
+                 orient: bool = True, chunk_fn_s=None, chunk_fn_e=None,
+                 bucket: bool = True, pipeline_depth: int = 1) -> PCRun:
+    """The Gaussian run from a device-resident f32 C, inside the caller's
+    open ``total`` span: the scan program or the host level loop."""
+    if E.is_whole_run(engine):
+        return _pc_run_scan(
+            c, m, alpha=alpha, max_level=max_level,
+            sepset_depth=sepset_depth, cell_budget=cell_budget,
+            orient=orient, tracer=tracer,
+        )
+    lmax = min(max_level if max_level is not None else MAX_LEVEL,
+               sepset_depth)
+    return _pc_run_host_loop(
+        c, test, engine=engine, lmax=lmax,
+        sepset_depth=sepset_depth, cell_budget=cell_budget,
+        orient=orient, bucket=bucket, chunk_fn_s=chunk_fn_s,
+        chunk_fn_e=chunk_fn_e, pipeline_depth=pipeline_depth,
+        tracer=tracer,
+    )
+
+
+def _finish(run: PCRun, tracer, driver: str, engine) -> PCRun:
+    """Fill the run's ``timings_s`` and ``counts`` from its tracer and write
+    the journal's closing ``run`` record."""
     run.timings_s = tracer.timings()
-    tracer.finish(driver="pc_from_corr", engine=str(engine),
+    run.counts = tracer.counts()
+    tracer.finish(driver=driver, engine=str(engine),
                   n=int(run.adj.shape[0]), levels_run=run.levels_run)
     return run
 
@@ -148,7 +171,9 @@ def _pc_run_host_loop(stats, test, *, engine, lmax, sepset_depth,
 
     Each span syncs the level's adjacency at exit, so span durations cover
     device time — exactly what the old block_until_ready + perf_counter
-    pairs measured."""
+    pairs measured. The max-degree read before each level runs in a
+    ``degree`` span and the result download in ``readback``; every
+    blocking read goes through ``obs.fetch``."""
     # C is (n, n); DiscreteStats carries (m, n) codes
     n = int(stats.codes.shape[1] if hasattr(stats, "codes")
             else stats.shape[0])
@@ -162,7 +187,9 @@ def _pc_run_host_loop(stats, test, *, engine, lmax, sepset_depth,
     stats_out = []
     ell = 1
     while ell <= lmax:
-        max_deg = int(jax.device_get(jnp.max(jnp.sum(adj, axis=1))))
+        with tracer.span("degree", level=ell):
+            max_deg = int(obs.fetch(jnp.max(jnp.sum(adj, axis=1)),
+                                    site="pc.degree"))
         if max_deg - 1 < ell:
             break
         with tracer.span(f"level{ell}", level=ell) as sp:
@@ -181,19 +208,19 @@ def _pc_run_host_loop(stats, test, *, engine, lmax, sepset_depth,
 
     with tracer.span("orient") as sp:
         if orient:
-            max_deg = int(jax.device_get(jnp.max(jnp.sum(adj, axis=1))))
+            max_deg = int(obs.fetch(jnp.max(jnp.sum(adj, axis=1)),
+                                    site="pc.orient_degree"))
             cpdag = cpdag_from_skeleton(adj, sep, n_prime=min(n, L.bucket_npr(max(max_deg, 1))))
         else:
             cpdag = adj
         sp.sync(cpdag)
 
-    return PCRun(
-        adj=np.asarray(jax.device_get(adj)),
-        cpdag=np.asarray(jax.device_get(cpdag)),
-        sepsets=np.asarray(jax.device_get(sep)),
-        levels_run=ell - 1,
-        level_stats=stats_out,
-    )
+    with tracer.span("readback"):
+        adj_h = np.asarray(obs.fetch(adj, site="pc.readback"))
+        cpdag_h = np.asarray(obs.fetch(cpdag, site="pc.readback"))
+        sep_h = np.asarray(obs.fetch(sep, site="pc.readback"))
+    return PCRun(adj=adj_h, cpdag=cpdag_h, sepsets=sep_h,
+                 levels_run=ell - 1, level_stats=stats_out)
 
 
 def _pc_run_scan(c, m, alpha, max_level, sepset_depth, cell_budget, orient,
@@ -226,17 +253,21 @@ def _pc_run_scan(c, m, alpha, max_level, sepset_depth, cell_budget, orient,
             cell_budget=cell_budget, orient=orient, test=test,
         )
         sp.sync(res.cpdag)
+    with tracer.span("readback"):
+        degs = np.asarray(obs.fetch(res.max_degs, site="scan.max_degs"))
+        adj_h = np.asarray(obs.fetch(res.adj, site="pc.readback"))
+        cpdag_h = np.asarray(obs.fetch(res.cpdag, site="pc.readback"))
+        sep_h = np.asarray(obs.fetch(res.sepsets, site="pc.readback"))
     # the host driver stops at the first level with max_deg - 1 < ell
-    degs = np.asarray(jax.device_get(res.max_degs))
     levels_run = 0
     for ell in range(1, lmax + 1):
         if degs[ell - 1] - 1 < ell:
             break
         levels_run = ell
     return PCRun(
-        adj=np.asarray(jax.device_get(res.adj)),
-        cpdag=np.asarray(jax.device_get(res.cpdag)),
-        sepsets=np.asarray(jax.device_get(res.sepsets)),
+        adj=adj_h,
+        cpdag=cpdag_h,
+        sepsets=sep_h,
         levels_run=levels_run,
         level_stats=[{"level": ell, "engine": "scan",
                       "skipped": ell > levels_run,
@@ -249,6 +280,7 @@ def _pc_discrete(
     x,
     test,
     *,
+    tracer,
     engine="auto",
     max_level=None,
     sepset_depth: int = 8,
@@ -260,49 +292,44 @@ def _pc_discrete(
     pipeline_depth: int = 1,
     validate: bool = True,
 ) -> PCRun:
-    """The discrete G² route of ``pc()``: encode level codes, rebind the
-    test's (m, r) to the data (the run-wide max arity is a static shape
-    parameter — see DiscreteCITest), then drive the SAME host loop / scan
-    program the Gaussian path uses, with DiscreteStats riding the stats
-    slot."""
+    """The discrete G² route of ``pc()``, inside its open ``total`` span:
+    encode level codes, rebind the test's (m, r) to the data (the run-wide
+    max arity is a static shape parameter — see DiscreteCITest), then drive
+    the SAME host loop / scan program the Gaussian path uses, with
+    DiscreteStats riding the stats slot."""
     if validate:
-        V.validate_discrete(x, max_level=max_level)
-    stats, r_max = encode_discrete(x)
+        with tracer.span("validate"):
+            V.validate_discrete(x, max_level=max_level)
+    with tracer.span("encode"):
+        stats, r_max = encode_discrete(x)
     test = dataclasses.replace(
         test, m=int(stats.codes.shape[0]), r=max(int(test.r), r_max)
     )
-    tracer = obs.run_tracer("pc_discrete")
-    with tracer.span("total", engine=str(engine)):
+    if max_level is None:
+        # cap where the contingency table still fits; an EXPLICIT deeper
+        # max_level is a user claim we reject loudly via check_level
+        lmax = min(MAX_LEVEL, sepset_depth, test.max_supported_level())
+    else:
+        lmax = min(max_level, sepset_depth)
+    test.check_level(lmax)
+    if E.is_whole_run(engine):
         if max_level is None:
-            # cap where the contingency table still fits; an EXPLICIT deeper
-            # max_level is a user claim we reject loudly via check_level
-            lmax = min(MAX_LEVEL, sepset_depth, test.max_supported_level())
-        else:
-            lmax = min(max_level, sepset_depth)
-        test.check_level(lmax)
-        if E.is_whole_run(engine):
-            if max_level is None:
-                # scan's static default cap, still bounded by the table cap
-                from repro.batch.scan_pc import DEFAULT_MAX_LEVEL
+            # scan's static default cap, still bounded by the table cap
+            from repro.batch.scan_pc import DEFAULT_MAX_LEVEL
 
-                lmax = min(lmax, DEFAULT_MAX_LEVEL)
-            run = _pc_run_scan(
-                stats, test.m, alpha=test.alpha, max_level=lmax,
-                sepset_depth=sepset_depth, cell_budget=cell_budget,
-                orient=orient, tracer=tracer, test=test,
-            )
-        else:
-            run = _pc_run_host_loop(
-                stats, test, engine=engine, lmax=lmax,
-                sepset_depth=sepset_depth, cell_budget=cell_budget,
-                orient=orient, bucket=bucket, chunk_fn_s=chunk_fn_s,
-                chunk_fn_e=chunk_fn_e, pipeline_depth=pipeline_depth,
-                tracer=tracer,
-            )
-    run.timings_s = tracer.timings()
-    tracer.finish(driver="pc_discrete", engine=str(engine),
-                  n=int(run.adj.shape[0]), levels_run=run.levels_run)
-    return run
+            lmax = min(lmax, DEFAULT_MAX_LEVEL)
+        return _pc_run_scan(
+            stats, test.m, alpha=test.alpha, max_level=lmax,
+            sepset_depth=sepset_depth, cell_budget=cell_budget,
+            orient=orient, tracer=tracer, test=test,
+        )
+    return _pc_run_host_loop(
+        stats, test, engine=engine, lmax=lmax,
+        sepset_depth=sepset_depth, cell_budget=cell_budget,
+        orient=orient, bucket=bucket, chunk_fn_s=chunk_fn_s,
+        chunk_fn_e=chunk_fn_e, pipeline_depth=pipeline_depth,
+        tracer=tracer,
+    )
 
 
 def pc(
@@ -334,29 +361,43 @@ def pc(
     but runs. validate=False restores the old trust-the-caller behaviour.
     The discrete route additionally demands non-negative integer codes
     (validate_discrete).
-    """
-    x = jnp.asarray(x)
-    t = resolve_citest(test, int(x.shape[0]), alpha)
-    if t.kind == "discrete":
-        if corr != "auto":
-            raise ValueError(
-                "corr= selects a correlation backend; the discrete G² test "
-                "does not compute correlations"
-            )
-        return _pc_discrete(x, t, engine=engine, max_level=max_level,
-                            validate=validate, **kw)
-    x = x.astype(jnp.float32)  # f32 on the device even when x64 is on
-    if validate:
-        V.validate_samples(x, max_level=max_level)
-    if corr not in ("auto", "kernel", "jnp"):
-        raise ValueError(f"corr must be auto|kernel|jnp, got {corr!r}")
-    use_kernel = corr == "kernel" or (corr == "auto" and jax.default_backend() == "tpu")
-    if use_kernel:
-        from repro.kernels.ops import correlation as corr_kernel
 
-        c = corr_kernel(x)
-    else:
-        c = correlation_from_samples(x)
-    # samples already validated and C built in-house — skip the re-check
-    return pc_from_corr(c, int(x.shape[0]), alpha=alpha, engine=engine,
-                        max_level=max_level, validate=False, test=t, **kw)
+    One tracer covers the whole call: ``timings_s["total"]`` runs from the
+    upload of x to the download of the results, and its children are
+    ``upload``, ``validate``, ``corr``, ``level0``, ``degree``/``level<l>``
+    pairs, ``orient`` and ``readback`` (docs/observability.md).
+    """
+    tracer = obs.run_tracer("pc")
+    with tracer.activate(), tracer.span("total", engine=str(engine)):
+        with tracer.span("upload"):
+            x = jnp.asarray(x)
+            t = resolve_citest(test, int(x.shape[0]), alpha)
+            if t.kind != "discrete":
+                x = x.astype(jnp.float32)  # f32 on the device even when x64 is on
+        if t.kind == "discrete":
+            if corr != "auto":
+                raise ValueError(
+                    "corr= selects a correlation backend; the discrete G² "
+                    "test does not compute correlations"
+                )
+            run = _pc_discrete(x, t, engine=engine, max_level=max_level,
+                               validate=validate, tracer=tracer, **kw)
+        else:
+            if validate:
+                with tracer.span("validate"):
+                    V.validate_samples(x, max_level=max_level)
+            if corr not in ("auto", "kernel", "jnp"):
+                raise ValueError(f"corr must be auto|kernel|jnp, got {corr!r}")
+            use_kernel = corr == "kernel" or (
+                corr == "auto" and jax.default_backend() == "tpu")
+            with tracer.span("corr", kernel=use_kernel):
+                if use_kernel:
+                    from repro.kernels.ops import correlation as corr_kernel
+
+                    c = corr_kernel(x)
+                else:
+                    c = correlation_from_samples(x)
+            # samples already validated and C built in-house: no re-check
+            run = _pc_gaussian(c, int(x.shape[0]), alpha, t, engine=engine,
+                               max_level=max_level, tracer=tracer, **kw)
+    return _finish(run, tracer, "pc", engine)

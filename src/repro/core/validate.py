@@ -35,9 +35,12 @@ to the device anyway; cost is one O(m·n + n²) pass.
 """
 from __future__ import annotations
 
+import sys
 import warnings
 
 import numpy as np
+
+from repro import obs
 
 
 class ValidationError(ValueError):
@@ -83,7 +86,11 @@ class BadDiscreteDataError(ValidationError):
 
 
 def _as_host(x) -> np.ndarray:
-    """Materialise on host without importing jax at module import time."""
+    """Materialise on host without importing jax at module import time; a
+    device array is read back through ``obs.fetch`` (a counted host sync)."""
+    jax = sys.modules.get("jax")
+    if jax is not None and isinstance(x, jax.Array):
+        return np.asarray(obs.fetch(x, site="validate"))
     return np.asarray(x)
 
 

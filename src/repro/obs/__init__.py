@@ -3,7 +3,9 @@
 Three pieces, one gate:
 
 * :mod:`~repro.obs.trace` — nestable trace spans with injectable clocks,
-  device-time-aware `sync`, optional jax-profiler annotation.
+  device-time-aware `sync`, optional jax-profiler annotation; `fetch`,
+  the one counted seam for blocking device reads; `current()`, the tracer
+  the enclosing driver opened.
 * :mod:`~repro.obs.metrics` — labeled counter/gauge/histogram registry +
   Prometheus text exposition; `record_level_stats` is the one shared
   definition of the dispatch/gather counters.
@@ -22,18 +24,19 @@ from .config import (ObsConfig, configure, disable, enable, enabled,
                      get_config, scoped)
 from .journal import SCHEMA_VERSION, Journal, phase_summary, read_journal
 from .metrics import (CHUNKS, COL_GATHER_BYTES, COL_GATHERS, DISPATCHES,
-                      LEVELS, TESTS_TOTAL, MetricsRegistry, get_registry,
-                      record_level_stats, scoped_registry)
-from .trace import (NULL_CTX, NULL_SPAN, ManualClock, MonotonicClock, Span,
-                    Tracer)
+                      HOST_SYNCS, LEVELS, TESTS_TOTAL, MetricsRegistry,
+                      get_registry, record_level_stats, scoped_registry)
+from .trace import (NULL_CTX, NULL_SPAN, NULL_TRACER, ManualClock,
+                    MonotonicClock, Span, Tracer, current, fetch)
 
 __all__ = [
     "ObsConfig", "configure", "enable", "disable", "enabled", "get_config",
     "scoped", "Journal", "read_journal", "phase_summary", "SCHEMA_VERSION",
     "MetricsRegistry", "get_registry", "scoped_registry", "record_level_stats",
     "DISPATCHES", "CHUNKS", "COL_GATHERS", "COL_GATHER_BYTES", "LEVELS",
-    "TESTS_TOTAL", "ManualClock", "MonotonicClock", "Span", "Tracer",
-    "NULL_SPAN", "NULL_CTX", "span", "journal_for", "run_tracer",
+    "TESTS_TOTAL", "HOST_SYNCS", "ManualClock", "MonotonicClock", "Span",
+    "Tracer", "NULL_SPAN", "NULL_CTX", "NULL_TRACER", "span", "journal_for",
+    "run_tracer", "current", "fetch",
 ]
 
 

@@ -29,6 +29,7 @@ COL_GATHERS = "pc_col_gathers_total"        # C[:, cols] all-gather collectives
 COL_GATHER_BYTES = "pc_col_gather_bytes_total"
 LEVELS = "pc_levels_total"                  # levels executed
 TESTS_TOTAL = "pc_ci_sets_total"            # candidate (edge, sepset) pairs
+HOST_SYNCS = "pc_host_syncs_total"          # blocking device reads, by site
 
 
 def _lkey(labels: dict) -> tuple:

@@ -20,7 +20,17 @@ with ONE seam:
 * ``profiler=True`` additionally brackets every span in a
   ``jax.profiler.TraceAnnotation``, so host spans line up with compiled-
   backend traces in TensorBoard/perfetto when a ``jax.profiler.trace`` is
-  active around the run.
+  active around the run. The annotation opens before the span reads
+  ``t0`` and closes after its syncs and ``t1``, so the span's device work
+  lies inside its annotation on the profiler's clock.
+* blocking reads are counted: :func:`fetch` is the one seam through which
+  the ``pc`` host path reads device arrays back (a ``sync`` child span
+  plus one ``host_syncs`` count), and a span's own ``sync()`` wait runs
+  in such a child span too. ``Tracer.count`` keeps per-run counts beside
+  the spans and mirrors them into the metrics registry when obs is on.
+* :func:`current` returns the tracer a driver opened with
+  ``Tracer.activate()`` (one context variable), or a disabled tracer, so
+  deeper layers add spans without a tracer argument in every signature.
 
 ``Tracer.timings()`` is the back-compat bridge: it renders the span list
 as the ``{name: seconds}`` dict the ``PCRun.timings_s`` field has always
@@ -28,9 +38,17 @@ carried, so existing callers and benchmarks keep working unchanged.
 """
 from __future__ import annotations
 
+import contextvars
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+from . import metrics
+from .config import enabled
+
+#: run count of the blocking device reads (``Tracer.count``); the registry
+#: series is ``pc_host_syncs_total{site}`` (metrics.HOST_SYNCS)
+SYNC_COUNT = "host_syncs"
 
 
 class MonotonicClock:
@@ -81,7 +99,8 @@ class Span:
 
     def sync(self, *arrays) -> "Span":
         """Register device arrays to ``block_until_ready`` at span exit, so
-        the recorded duration covers device time, not just dispatch time."""
+        the recorded duration covers device time, not just dispatch time.
+        The wait runs in a ``sync`` child span and counts one host sync."""
         self._sync = self._sync + tuple(arrays)
         return self
 
@@ -119,8 +138,8 @@ NULL_CTX = _NullCtx()
 
 
 class Tracer:
-    """Collects a run's spans (completion order) and optionally streams
-    each finished span to a :class:`repro.obs.journal.Journal`."""
+    """Collects a run's spans (completion order) and counts, and optionally
+    streams each finished span to a :class:`repro.obs.journal.Journal`."""
 
     def __init__(self, name: str = "run", *, clock=None, enabled: bool = True,
                  journal=None, profiler: bool = False):
@@ -131,41 +150,77 @@ class Tracer:
         self.profiler = bool(profiler)
         self.spans: list[Span] = []
         self._stack: list[Span] = []
+        self._counts: dict[str, float] = {}
+
+    def span(self, name: str, **attrs):
+        """Context manager of one nested span; the shared no-op context
+        when the tracer is disabled."""
+        if not self.enabled:
+            return NULL_CTX
+        return self._span(name, attrs)
 
     @contextmanager
-    def span(self, name: str, **attrs):
-        if not self.enabled:
-            yield NULL_SPAN
-            return
+    def _span(self, name: str, attrs: dict):
         parent = self._stack[-1] if self._stack else None
         path = f"{parent.path}/{name}" if parent is not None else name
-        sp = Span(name=name, path=path, depth=len(self._stack),
-                  t0=self.clock.now(), attrs=dict(attrs))
-        self._stack.append(sp)
         ann = None
         if self.profiler:
             import jax.profiler
 
             ann = jax.profiler.TraceAnnotation(path)
             ann.__enter__()
+        sp = Span(name=name, path=path, depth=len(self._stack),
+                  t0=self.clock.now(), attrs=dict(attrs))
+        self._stack.append(sp)
         try:
             yield sp
         except BaseException as e:
             sp.attrs.setdefault("error", type(e).__name__)
             raise
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            if sp._sync:
-                import jax
+            try:
+                if sp._sync:
+                    self._block(sp)
+                sp.t1 = self.clock.now()
+                self._stack.pop()
+                self.spans.append(sp)
+                if self.journal is not None:
+                    self.journal.span(sp)
+            finally:
+                if ann is not None:
+                    ann.__exit__(None, None, None)
 
-                for a in sp._sync:
-                    jax.block_until_ready(a)
-            sp.t1 = self.clock.now()
-            self._stack.pop()
-            self.spans.append(sp)
-            if self.journal is not None:
-                self.journal.span(sp)
+    def _block(self, sp: Span):
+        """Wait for the arrays ``sp.sync`` registered, in a ``sync`` child
+        span, counted as one host sync."""
+        import jax
+
+        with self.span("sync", site=sp.name):
+            for a in sp._sync:
+                jax.block_until_ready(a)
+        self.count(SYNC_COUNT, site=sp.name)
+
+    @contextmanager
+    def activate(self):
+        """Make this the tracer :func:`current` returns while the block
+        runs (drivers open it around their ``total`` span)."""
+        token = _CURRENT.set(self)
+        try:
+            yield self
+        finally:
+            _CURRENT.reset(token)
+
+    def count(self, name: str, n: float = 1, **labels):
+        """Add ``n`` to the run's count ``name``; when obs is enabled the
+        registry's ``pc_<name>_total{labels}`` gets the same increment."""
+        if self.enabled:
+            self._counts[name] = self._counts.get(name, 0) + n
+        if enabled():
+            metrics.get_registry().inc(f"pc_{name}_total", n, **labels)
+
+    def counts(self) -> dict:
+        """The run's counts by name, summed over labels (``PCRun.counts``)."""
+        return dict(self._counts)
 
     # -- derived views -------------------------------------------------------
     def timings(self) -> dict:
@@ -185,5 +240,31 @@ class Tracer:
         if self.journal is not None:
             self.journal.record("run", name=self.name,
                                 ts=self.clock.now(),
-                                timings_s=self.timings(), attrs=attrs)
+                                timings_s=self.timings(),
+                                counts=self.counts(), attrs=attrs)
             self.journal.close()
+
+
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar("repro_obs_tracer",
+                                                         default=None)
+#: what :func:`current` returns with no tracer open: spans are no-ops and
+#: counts reach only the registry (when obs is enabled)
+NULL_TRACER = Tracer("none", enabled=False)
+
+
+def current() -> Tracer:
+    """The tracer the enclosing driver activated, else :data:`NULL_TRACER`."""
+    return _CURRENT.get() or NULL_TRACER
+
+
+def fetch(x, *, site: str):
+    """``jax.device_get(x)`` as a counted blocking read: inside a ``sync``
+    span (attribute ``site``) of the current tracer, plus one
+    ``host_syncs`` count labeled ``site``. Returns what device_get returns."""
+    import jax
+
+    tr = current()
+    with tr.span("sync", site=site):
+        out = jax.device_get(x)
+    tr.count(SYNC_COUNT, site=site)
+    return out

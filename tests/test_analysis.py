@@ -81,6 +81,19 @@ class TestRPR001:
         assert _codes(fs) == ["RPR001"]
         assert fs[0].detail == "float()"
 
+    def test_obs_fetch_in_jitted_body_fires_once(self):
+        src = """
+        import jax
+        from repro import obs
+
+        @jax.jit
+        def step(x):
+            return x * obs.fetch(x.max(), site="step")
+        """
+        fs = _check(src)
+        assert _codes(fs) == ["RPR001"]
+        assert fs[0].detail == "obs.fetch"
+
 
 class TestRPR002:
     VIOLATION = """
@@ -113,6 +126,23 @@ class TestRPR002:
         fs = _check(src)
         assert _codes(fs) == ["RPR002"]
         assert fs[0].detail == "np.asarray(device_get)"
+
+    def test_obs_fetch_is_a_seam(self):
+        src = """
+        import numpy as np
+        from repro import obs
+
+        def degree(adj):
+            return int(obs.fetch(adj.sum(1).max(), site="degree"))
+
+        def materialize(x):
+            return np.asarray(obs.fetch(x, site="readback"))
+        """
+        fs = _check(src)
+        assert _codes(fs) == ["RPR002", "RPR002"]
+        assert [f.key for f in fs] == [
+            "RPR002 src/repro/core/mod.py::degree::obs.fetch",
+            "RPR002 src/repro/core/mod.py::materialize::np.asarray(obs.fetch)"]
 
 
 class TestRPR003:

@@ -200,16 +200,32 @@ def test_pc_journal_spans_reconcile_with_total(tmp_path):
     with obs.scoped(enabled=True, journal_path=path):
         run = pc(x, alpha=0.01)
     recs = obs.read_journal(path)
-    phases = obs.phase_summary(recs, depth=1)
-    # every timings_s phase appears in the journal with the same duration
+    spans = [r for r in recs if r["kind"] == "span"]
+    depths = sorted({r["depth"] for r in spans})
+    by_depth = {d: obs.phase_summary(recs, depth=d) for d in depths}
+    # every timings_s phase appears in the journal with the same duration,
+    # summed over the depths it occurs at (``sync`` and ``degree`` sit both
+    # directly under ``total`` and inside a level)
     for k, v in run.timings_s.items():
         if k == "total":
             continue
-        assert phases[k] == pytest.approx(v)
-    assert sum(phases.values()) <= run.timings_s["total"] + 1e-6
-    assert sum(phases.values()) >= 0.5 * run.timings_s["total"]
+        got = sum(by_depth[d].get(k, 0.0) for d in depths)
+        assert got == pytest.approx(v)
+    assert set(run.timings_s) == {r["name"] for r in spans}
+    # at each depth the children fit inside their parents, and the depth-1
+    # phases account for at least half of the total
+    phases = by_depth[1]
+    total = run.timings_s["total"]
+    assert sum(phases.values()) <= total + 1e-6
+    assert sum(phases.values()) >= 0.5 * total
+    for sp in spans:
+        kids = [r["dur_s"] for r in spans if r["depth"] == sp["depth"] + 1
+                and r["path"].rsplit("/", 1)[0] == sp["path"]
+                and sp["t0"] <= r["t0"] <= sp["t1"]]
+        assert sum(kids) <= sp["dur_s"] + 1e-6
     run_rec = [r for r in recs if r["kind"] == "run"]
     assert len(run_rec) == 1 and run_rec[0]["timings_s"] == run.timings_s
+    assert run_rec[0]["counts"] == run.counts
 
 
 def test_zero_overhead_contract_disabled_obs(tmp_path):
@@ -257,6 +273,255 @@ def test_registry_counts_match_level_stats():
         assert reg.total(obs.CHUNKS, layout="single") == \
             sum(st.get("chunks", 0) for st in run.level_stats)
         assert reg.total(obs.LEVELS) == len(run.level_stats)
+
+
+# ----------------------------------- annotations, span tree, host syncs
+def test_annotation_brackets_span_and_its_sync(monkeypatch):
+    """With the profiler on, a span's annotation opens before t0 and closes
+    after its sync and t1, so its device work lies inside the annotation;
+    the sync itself runs in a counted ``sync`` child span."""
+    import jax
+    import jax.profiler
+
+    events = []
+
+    class Clock(obs.ManualClock):
+        def now(self):
+            t = super().now()
+            events.append(("now", t))
+            return t
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+            return False
+
+    def block(a):
+        clk.advance(2.0)  # the device work the span waits for
+        events.append(("block", a))
+        return a
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    clk = Clock()
+    tr = obs.Tracer("t", clock=clk, profiler=True)
+    with tr.span("level1") as sp:
+        clk.advance(1.0)
+        sp.sync("adj")
+    assert events == [
+        ("enter", "level1"), ("now", 0.0),
+        ("enter", "level1/sync"), ("now", 1.0), ("block", "adj"),
+        ("now", 3.0), ("exit", "level1/sync"),
+        ("now", 3.0), ("exit", "level1"),
+    ]
+    done = {s.name: s for s in tr.spans}
+    assert (done["level1"].t0, done["level1"].t1) == (0.0, 3.0)
+    assert done["sync"].path == "level1/sync"
+    assert done["sync"].attrs == {"site": "level1"}
+    assert tr.counts() == {"host_syncs": 1}
+
+
+def test_annotation_closes_when_the_span_raises(monkeypatch):
+    import jax.profiler
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            opened.remove(opened[-1])
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = obs.Tracer(clock=obs.ManualClock(), profiler=True)
+    with pytest.raises(ValueError):
+        with tr.span("outer"), tr.span("inner"):
+            raise ValueError("boom")
+    assert opened == [] and tr._stack == []
+    assert [s.attrs["error"] for s in tr.spans] == ["ValueError", "ValueError"]
+
+
+def test_current_tracer_and_fetch():
+    import jax.numpy as jnp
+
+    assert obs.current() is obs.NULL_TRACER
+    x = jnp.arange(4)
+    # no tracer open: a plain read, nothing recorded
+    np.testing.assert_array_equal(obs.fetch(x, site="t"), np.arange(4))
+    assert obs.NULL_TRACER.spans == [] and obs.NULL_TRACER.counts() == {}
+    tr = obs.Tracer("t", clock=obs.ManualClock())
+    with obs.scoped(enabled=True), obs.scoped_registry() as reg:
+        with tr.activate():
+            assert obs.current() is tr
+            with obs.current().span("outer"):
+                obs.fetch(x, site="a")
+                obs.fetch(x, site="b")
+        assert obs.current() is obs.NULL_TRACER
+        obs.fetch(x, site="a")  # outside the tracer: registry only
+        assert reg.value(obs.HOST_SYNCS, site="a") == 2
+        assert reg.value(obs.HOST_SYNCS, site="b") == 1
+    assert [s.path for s in tr.spans] == ["outer/sync", "outer/sync", "outer"]
+    assert [s.attrs["site"] for s in tr.spans[:2]] == ["a", "b"]
+    assert tr.counts() == {"host_syncs": 2}
+
+
+N_TREE = 60
+LEVEL_NAME = __import__("re").compile(r"level\d")
+
+
+@pytest.fixture(scope="module")
+def auto_runs(tmp_path_factory):
+    """One pc call at n = 60 through the kernel engines (interpret mode)
+    per depth cap, journaled, with every blocking read primitive counted."""
+    import jax
+
+    from repro.core.pc import pc
+
+    x = _x(n=N_TREE, seed=0, m=400)
+    out = {}
+    for cap in (1, None):
+        path = str(tmp_path_factory.mktemp("tree") / "pc.jsonl")
+        calls = {"device_get": 0, "block_until_ready": 0}
+        real = {k: getattr(jax, k) for k in calls}
+
+        def counted(name):
+            def f(*a, **kw):
+                calls[name] += 1
+                return real[name](*a, **kw)
+            return f
+
+        for k in calls:
+            setattr(jax, k, counted(k))
+        try:
+            with obs.scoped(enabled=True, journal_path=path), \
+                    obs.scoped_registry() as reg:
+                run = pc(x, alpha=0.01, engine="auto", max_level=cap)
+                registry = reg.collect()
+        finally:
+            for k, f in real.items():
+                setattr(jax, k, f)
+        out[cap] = (run, obs.read_journal(path), calls, registry)
+    return out
+
+
+def _children(recs, parent="total"):
+    return [r["name"] for r in recs if r["kind"] == "span"
+            and r["path"] == f"{parent}/{r['name']}"]
+
+
+@pytest.mark.parametrize("cap", [1, None], ids=["max_level1", "full_depth"])
+def test_pc_span_tree(auto_runs, cap):
+    run, recs, _, _ = auto_runs[cap]
+    kids = _children(recs)
+    levels = [f"level{ell}" for ell in range(1, run.levels_run + 1)]
+    want = ["upload", "validate", "corr", "level0"]
+    for lv in levels:
+        want += ["degree", lv]
+    if cap is None:
+        want += ["degree"]  # the read that finds no further level to run
+    want += ["orient", "readback"]
+    assert kids == want
+    if cap is None:
+        assert run.levels_run >= 2
+    names = {r["name"] for r in recs if r["kind"] == "span"}
+    assert {n for n in names if LEVEL_NAME.match(n)} == {"level0", *levels}
+    assert set(run.timings_s) == names
+    # each level first reads back its threshold; level 1 (dense kernel)
+    # reads its own degree; levels >= 2 plan, then issue one chunk span per
+    # dispatched chunk program
+    spans = [r for r in recs if r["kind"] == "span"]
+    assert _children(recs, "total/level0") == ["sync", "sync"]
+    assert _children(recs, "total/level1") == ["sync", "degree", "sync"]
+    for st in run.level_stats:
+        if st["level"] < 2:
+            continue
+        lv = f"total/level{st['level']}"
+        assert _children(recs, lv) == (["sync", "plan"] + ["chunk"] * st["chunks"]
+                                       + ["sync"])
+        t0s = [r["attrs"]["t0"] for r in spans if r["path"] == f"{lv}/chunk"]
+        assert t0s == list(range(0, st["total_sets"], st["n_chunk"]))
+    # every sync span names its site; the readback reads three arrays
+    assert all("site" in r["attrs"] for r in spans if r["name"] == "sync")
+    assert _children(recs, "total/readback") == ["sync"] * 3
+    # the children of total cover it, up to the tracer's own bookkeeping
+    top = sum(r["dur_s"] for r in spans if r["depth"] == 1)
+    assert top <= run.timings_s["total"] + 1e-9
+
+
+@pytest.mark.parametrize("cap,want", [(1, 12), (None, 25)],
+                         ids=["max_level1", "full_depth"])
+def test_pc_host_syncs_exact(auto_runs, cap, want):
+    """The blocking reads of a fixed small run, counted exactly: validate's
+    read of x, level 0's threshold and sync, per level a degree read, its
+    threshold, the level's own degree or plan read and its sync, the read
+    that ends the loop, orient's degree read and sync, and three readbacks.
+    A new read fails here."""
+    run, recs, calls, registry = auto_runs[cap]
+    assert run.levels_run == (1 if cap == 1 else 4)
+    assert run.counts["host_syncs"] == want
+    assert calls["device_get"] + calls["block_until_ready"] == want
+    syncs = [r for r in recs if r["kind"] == "span" and r["name"] == "sync"]
+    assert len(syncs) == want
+    series = registry[obs.HOST_SYNCS]["series"]
+    assert sum(s["value"] for s in series) == want
+    sites = {s["labels"]["site"] for s in series}
+    assert {"validate", "cit.threshold", "level0", "pc.degree",
+            "engines.dense_l1_degree", "level1", "pc.orient_degree", "orient",
+            "pc.readback"} <= sites
+    assert ("levels.plan" in sites) == (cap is None)
+
+
+def test_pc_outputs_identical_with_profiler_annotations(auto_runs):
+    """The spans, syncs and annotations change no output bit: the same call
+    with obs off, and with obs and profiler annotations on."""
+    from repro.core.pc import pc
+
+    ref, _, _, _ = auto_runs[1]
+    x = _x(n=N_TREE, seed=0, m=400)
+    off = pc(x, alpha=0.01, engine="auto", max_level=1)
+    with obs.scoped(enabled=True, jax_profiler=True), obs.scoped_registry():
+        on = pc(x, alpha=0.01, engine="auto", max_level=1)
+    for run in (off, on):
+        np.testing.assert_array_equal(run.adj, ref.adj)
+        np.testing.assert_array_equal(run.cpdag, ref.cpdag)
+        np.testing.assert_array_equal(run.sepsets, ref.sepsets)
+        assert run.counts == ref.counts
+
+
+def test_scan_engine_span_tree():
+    from repro.core.pc import pc
+
+    run = pc(_x(seed=2), alpha=0.01, engine="scan", max_level=2)
+    assert list(run.timings_s) == ["upload", "sync", "validate", "corr",
+                                   "scan", "readback", "total"]
+    # validate, the thresholds of levels 0-2, the scan program's plan read
+    # and sync, four readbacks
+    assert run.counts == {"host_syncs": 10}
+
+
+def test_pc_from_corr_opens_its_own_tracer():
+    from repro.core.cit import correlation_from_samples
+    from repro.core.pc import pc_from_corr
+
+    c = np.asarray(correlation_from_samples(_x(seed=5)))
+    run = pc_from_corr(c, M, alpha=0.01, engine="S", max_level=1)
+    assert obs.current() is obs.NULL_TRACER
+    assert {"validate", "upload", "level0", "readback", "total"} <= set(run.timings_s)
+    assert "corr" not in run.timings_s
+    # a host C is validated without a read; the device reads as in pc
+    assert run.counts["host_syncs"] >= 5
 
 
 # ---------------------------------------------------------------- serving
