@@ -171,11 +171,18 @@ def check_mosaic(m, alpha, runs):
             if fn is None:
                 want[name] = (jax.jit(ops.level1_dense).lower(c, adj, tau), expect)
                 continue
+            sep = sd((n, n, run.sepsets.shape[-1]), jnp.int32)
+            if "classes" in st:  # S-kernel at l >= 2: one degree-class program
+                k = st["classes"][-1]
+                want[name] = (ops.chunk_s_kernel_class.lower(
+                    c, adj, sep, sd((n, n), jnp.int32), sd((k["rows_bucket"],), jnp.int32),
+                    sd((), jnp.int32), tau, ell=st["level"], n_chunk=k["n_chunk"],
+                    n_max=k["bucket"]), expect)
+                continue
             ell, n_chunk, npr_b = st["compile_key"]
             want[name] = (fn.lower(
-                c, adj, sd((n, n, run.sepsets.shape[-1]), jnp.int32),
-                sd((n, npr_b), jnp.int32), sd((n,), jnp.int32), sd((), jnp.int32),
-                tau, ell=ell, n_chunk=n_chunk, n_max=npr_b), expect)
+                c, adj, sep, sd((n, npr_b), jnp.int32), sd((n,), jnp.int32),
+                sd((), jnp.int32), tau, ell=ell, n_chunk=n_chunk, n_max=npr_b), expect)
     if len(want) != 4:
         fail(f"expected all four Mosaic programs to have run; found {sorted(want)}")
     for name, (lowered, expect) in sorted(want.items()):

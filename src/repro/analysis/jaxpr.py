@@ -155,7 +155,9 @@ def kernel_count_findings(fn, expected: int, *args, name: str = "",
 def stats_contract_findings(level_stats, path: str = "<run>") -> list[Finding]:
     """Verify a live run's per-level stats obey the PR-5 planner arithmetic:
     ``chunks == ceil(total_sets/n_chunk)`` and ``dispatches == chunks ×
-    (2 if pipelined else 1)``. ``level_stats``: iterable of stats dicts
+    (2 if pipelined else 1)``; a degree-class level
+    (levels.run_level_classes) sums its classes' chunks and adds its one
+    commit program. ``level_stats``: iterable of stats dicts
     (PCRun.level_stats)."""
     out = []
     for i, st in enumerate(level_stats):
@@ -173,9 +175,16 @@ def stats_contract_findings(level_stats, path: str = "<run>") -> list[Finding]:
                             f"n_chunk={n_chunk} (expected {want_chunks})",
                     context=ctx, detail="chunks",
                 ))
+        if "classes" in st and chunks != sum(k["chunks"] for k in st["classes"]):
+            out.append(Finding(
+                code=RPR103, path=path, line=0,
+                message=f"{ctx}: {chunks} chunks but its classes ran "
+                        f"{sum(k['chunks'] for k in st['classes'])}",
+                context=ctx, detail="chunks",
+            ))
         if chunks is not None and disp is not None:
             mult = 2 if st.get("pipeline_depth", 1) > 1 else 1
-            if disp != chunks * mult:
+            if disp != chunks * mult + ("classes" in st):
                 out.append(Finding(
                     code=RPR103, path=path, line=0,
                     message=f"{ctx}: dispatches={disp} but chunks={chunks} "
@@ -287,6 +296,17 @@ def entry_points() -> list[Entry]:
         c, adj, sep, compact, counts, t0, tau, kw = _gauss_chunk_args()
         return ops.chunk_s_grid, (c, adj, sep, compact, counts, t0, tau), kw
 
+    def chunk_s_kernel_class():
+        import jax.numpy as jnp
+
+        from repro.core import levels as L
+        from repro.kernels import ops
+        c, adj, sep, _, _, t0, tau, kw = _gauss_chunk_args()
+        n = adj.shape[0]
+        key = jnp.full((n, n), L._imax(), L._rank_dtype())
+        rows = jnp.asarray([0, 3, 5, -1, -1, -1, -1, -1], jnp.int32)
+        return ops.chunk_s_kernel_class, (c, adj, sep, key, rows, t0, tau), kw
+
     def chunk_g2():
         import jax.numpy as jnp
 
@@ -354,6 +374,7 @@ def entry_points() -> list[Entry]:
         Entry("chunk_g2_kernel", chunk_g2_kernel, 1, f"{c}/levels.py"),
         Entry("chunk_s_kernel", chunk_s_kernel, 2, f"{k}/ops.py"),
         Entry("chunk_s_grid", chunk_s_grid, 1, f"{k}/ops.py"),
+        Entry("chunk_s_kernel_class", chunk_s_kernel_class, 2, f"{k}/ops.py"),
         Entry("level1_dense", level1_dense, 1, f"{k}/ops.py"),
         Entry("level0", level0, 1, f"{k}/ops.py"),
         Entry("correlation", correlation, 1, f"{k}/ops.py"),

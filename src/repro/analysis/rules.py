@@ -79,6 +79,8 @@ ALLOWLIST: dict[str, str] = {
     # ---- depend on the device-side max degree; one sync per level by design
     "RPR002 src/repro/core/levels.py::run_level::np.asarray(obs.fetch)":
         "per-level plan barrier: chunk shapes derive from the device max degree",
+    "RPR002 src/repro/core/levels.py::run_level_classes::np.asarray(obs.fetch)":
+        "per-level plan barrier: the degree classes derive from the row counts",
     "RPR002 src/repro/core/pc.py::_pc_run_host_loop::obs.fetch":
         "level-ladder barrier: max_deg decides whether another level runs",
     "RPR002 src/repro/core/distributed.py::run_level_sharded::np.asarray(device_get)":

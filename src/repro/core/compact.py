@@ -17,13 +17,14 @@ def compact_rows(adj: jax.Array, n_prime: int | None = None):
     """Compact each row of a boolean adjacency matrix.
 
     Returns (compact, counts):
-      compact: (n, n′) int32, neighbour column ids, -1 padded
-      counts:  (n,)    int32, n'_i
+      compact: (rows, n′) int32, neighbour column ids, -1 padded
+      counts:  (rows,)    int32, n'_i
 
-    n_prime: static output width. If None, uses n (fully dynamic callers
-    should pass the previous level's bound to keep shapes tight).
+    adj may be a block of rows (rows, n) of the (n, n) matrix. n_prime:
+    static output width. If None, uses n (fully dynamic callers should pass
+    the previous level's bound to keep shapes tight).
     """
-    n = adj.shape[0]
+    n = adj.shape[-1]
     width = n if n_prime is None else n_prime
     adj = adj.astype(bool)
     counts = jnp.sum(adj, axis=1).astype(jnp.int32)

@@ -10,7 +10,10 @@ Names (case-insensitive; ``pc()`` / ``pc_from_corr()`` accept a name or a
               fidelity engine, no pseudo-inverse sharing.
   "S-kernel"  cuPC-S with the per-set Cholesky inverse + CI sweep fused in
               the Pallas kernels (kernels/ops.chunk_s_kernel → cholinv +
-              cisweep); gathers stay in XLA. Any level ℓ ≥ 1.
+              cisweep); gathers stay in XLA. Any level ℓ ≥ 1; at ℓ ≥ 2 as
+              degree-class worklists (levels.run_level_classes): each row
+              launches only its own rank range and neighbour slots. A
+              custom ``chunk_fn_s`` replaces its ℓ = 1 chunk function only.
   "S-grid"    grid-resident cuPC-S (kernels/ops.chunk_s_grid → sgrid): the
               combo-rank loop is a sequential axis of the Pallas grid, the
               winner arrays accumulate in the revisited VMEM output blocks
@@ -182,10 +185,14 @@ def run_level(
     elif name == "S-kernel":
         from repro.kernels.ops import chunk_s_kernel
 
-        adj, sep, st = L.run_level(
-            c, adj, sep, ell, tau, engine="S", cell_budget=cell_budget,
-            chunk_fn_s=chunk_fn_s or chunk_s_kernel, bucket=bucket,
-        )
+        if ell >= 2:
+            adj, sep, st = L.run_level_classes(c, adj, sep, ell, tau,
+                                               cell_budget=cell_budget, bucket=bucket)
+        else:
+            adj, sep, st = L.run_level(
+                c, adj, sep, ell, tau, engine="S", cell_budget=cell_budget,
+                chunk_fn_s=chunk_fn_s or chunk_s_kernel, bucket=bucket,
+            )
         st["engine"] = "S-kernel"
     elif name == "S-grid":
         from repro.kernels.ops import chunk_s_grid

@@ -34,7 +34,7 @@ module owns the jnp engines, the chunk planner and the commit layer):
   ─────────  ──────────────────────  ───────────────────  ─────────────────────
   S          chunk_s                 chunk_s              any (XLA einsums)
   E          chunk_e                 chunk_e              any (XLA einsums)
-  S-kernel   ops.chunk_s_kernel      ops.chunk_s_kernel   Pallas (interp off-TPU)
+  S-kernel   ops.chunk_s_kernel      run_level_classes    Pallas (interp off-TPU)
   S-grid     ops.chunk_s_grid        ops.chunk_s_grid     Pallas (interp off-TPU)
   L1-dense   ops.level1_dense        (resolves to S)      Pallas (interp off-TPU)
   auto       L1-dense                S-kernel             Pallas (interp off-TPU)
@@ -45,11 +45,18 @@ rank-chunk length is a power of two derived from a VMEM-aware cell budget.
 Both static shapes therefore recur across levels and runs instead of
 retriggering one XLA/Mosaic compile per exact max-degree — level boundaries
 reuse the jit cache (probed by tests/test_engines.py).
+
+Degree classes (``plan_classes`` / ``run_level_classes``, the S-kernel route
+at ℓ ≥ 2): one level-wide plan launches every row at the largest degree's
+rank range and width, so a few hubs set the work of all n rows. The class
+planner groups the rows by their own n′ bucket instead, and each class runs
+only its own ranks and slots; the claims merge and commit once per level.
 """
 from __future__ import annotations
 
 import functools
 import math
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -528,21 +535,33 @@ def _winners(sep_found, ranks, s_ids_shared, s_ids_per_edge):
     return t_win, removed_slot, s_win
 
 
+def _slot_keys(rows, j_ids, t_win, removed_slot):
+    """Row i's claim on edge (i, j) per slot: rank·2 + endpoint-order for
+    winner slots, imax elsewhere — the one key every commit compares."""
+    order_bit = (rows[:, None] > j_ids).astype(_rank_dtype())
+    return jnp.where(removed_slot, t_win * 2 + order_bit, _imax())
+
+
+def _use_own(key_own, key_other):
+    """The endpoint tie-break of every commit: where both endpoints of an
+    edge claim it, the sepset comes from the row whose key is the smaller,
+    and from row i itself on a tie (keys differ in their endpoint-order
+    bit, so a tie means neither claimed)."""
+    return key_own <= key_other
+
+
 def _commit_key_mat(compact_full, rows_full, t_win, removed_slot, n):
     """Scatter per-(row, slot) winner ranks into the dense (n, n) key matrix.
 
-    key_mat[i, j] is row i's claim on edge (i, j): rank·2 + endpoint-order
-    for winner slots, imax elsewhere. The symmetric edge decision is then
-    min(key_mat, key_mat.T) — shared by the replicated commit
-    (:func:`_global_commit`) and the row-sharded sepset commit
+    key_mat[i, j] is row i's claim on edge (i, j) (:func:`_slot_keys`). The
+    symmetric edge decision is then min(key_mat, key_mat.T) — shared by the
+    replicated commit (:func:`_global_commit`) and the row-sharded sepset commit
     (:func:`commit_sep_rows`), so the two layouts cannot diverge on which
     endpoint's separating set wins. Returns (j_ids (n, npr), key_mat (n, n)).
     """
-    imax = _imax()
     j_ids = jnp.clip(compact_full, 0, n - 1)
-    order_bit = (rows_full[:, None] > j_ids).astype(_rank_dtype())
-    key = jnp.where(removed_slot, t_win * 2 + order_bit, imax)
-    key_mat = jnp.full((n, n), imax, dtype=_rank_dtype()).at[rows_full[:, None], j_ids].min(key)
+    key = _slot_keys(rows_full, j_ids, t_win, removed_slot)
+    key_mat = jnp.full((n, n), _imax(), dtype=_rank_dtype()).at[rows_full[:, None], j_ids].min(key)
     return j_ids, key_mat
 
 
@@ -567,7 +586,7 @@ def _global_commit(adj, sep, compact_full, rows_full, t_win, removed_slot, s_win
     )
     final_key = jnp.minimum(key_mat, key_mat.T)
     newly_removed = final_key < imax  # (n,n) symmetric
-    use_own = key_mat <= key_mat.T
+    use_own = _use_own(key_mat, key_mat.T)
     s_final = jnp.where(use_own[..., None], s_mat, jnp.swapaxes(s_mat, 0, 1))
 
     adj_new = adj & ~newly_removed
@@ -604,9 +623,9 @@ def commit_sep_rows(sep_rows, row_ids, adj, key_mat, compact_full, removed_slot,
       * every other row j's winner slot targeting i (the transposed claim —
         scattered by (j_ids[j, p] → local row, source j)).
 
-    The per-edge tie-break (``key_own <= key_oth``) replays
-    :func:`_global_commit`'s ``use_own`` rule exactly, so the sharded and
-    replicated layouts commit bit-identical sepsets (tests/test_sharding.py).
+    The per-edge tie-break (:func:`_use_own`) is :func:`_global_commit`'s
+    own, so the sharded and replicated layouts commit bit-identical sepsets
+    (tests/test_sharding.py).
 
     sep_rows:     (n_l, n, Lmax) this shard's sepset rows;
     row_ids:      (n_l,) global row ids (ids ≥ n are shard padding — their
@@ -625,7 +644,7 @@ def commit_sep_rows(sep_rows, row_ids, adj, key_mat, compact_full, removed_slot,
     valid_row = row_ids < n
     key_own = key_mat[rid]  # (n_l, n): local rows' claims
     key_oth = key_mat.T[rid]  # (n_l, n): the other endpoints' claims
-    use_own = key_own <= key_oth
+    use_own = _use_own(key_own, key_oth)
     newly_removed = jnp.minimum(key_own, key_oth) < imax
 
     # own claims: scatter local winner slots by target column (losers → dump
@@ -938,4 +957,148 @@ def run_level(
         "compile_key": (ell, n_chunk, npr_b),
         "pipeline_depth": depth if pipelined else 1,
         "dispatches": chunks * (2 if pipelined else 1),
+    }
+
+
+# --------------------------------------------------------------------------
+# degree-class worklists (the S-kernel route at ℓ ≥ 2)
+# --------------------------------------------------------------------------
+#: Fewest rows a class launches: one sublane tile of the f32 gathers. The
+#: Pallas sweep flattens rows × ranks into its lane axis, so a small class
+#: (a handful of hubs) costs only its own rows.
+CLASS_ROW_FLOOR = 8
+
+
+@dataclass(frozen=True)
+class DegreeClass:
+    """One worklist of a level: the rows whose degree shares an n′ bucket.
+
+    rows: (rows_bucket,) global row ids, -1 padded; bucket: the class's slot
+    width n′ (its largest degree where planned without bucketing) and its
+    jit key's n_max; n_chunk: ranks per program; total:
+    C(max degree in the class, ℓ), the class's rank range."""
+
+    rows: np.ndarray
+    bucket: int
+    n_chunk: int
+    total: int
+
+    @property
+    def chunks(self) -> int:
+        return -(-self.total // self.n_chunk)
+
+    @property
+    def launched(self) -> int:
+        """(row, set, neighbour) cells the class's programs launch."""
+        return self.rows.size * self.chunks * self.n_chunk * self.bucket
+
+
+def plan_classes(counts: np.ndarray, ell: int,
+                 cell_budget: int = DEFAULT_CELL_BUDGET,
+                 bucket: bool = True) -> list[DegreeClass]:
+    """Partition a level's rows into degree classes (host side).
+
+    Rows with d_i ≤ ℓ hold no ℓ-set beside a neighbour and are dropped; the
+    rest group by ``bucket_npr(d_i)``. Each class pads its rows to a power
+    of two (at least :data:`CLASS_ROW_FLOOR`, at most n) and takes its
+    chunk length from :func:`plan_level` at its own row count, width and
+    rank range, so the jit keys recur across levels and graphs. Where the
+    classes would launch more cells than one class of all n rows at the
+    level-wide plan (power-of-two rounding on a near-uniform graph), that
+    one class is returned: no graph launches more than the uniform plan.
+    ``bucket=False`` gives each class :func:`plan_level`'s exact width and
+    chunk length (its largest degree) in place of the bucketed ones.
+    """
+    n = counts.shape[0]
+    uniform = DegreeClass(np.arange(n, dtype=np.int32), *plan_level(
+        int(counts.max()), ell, n, cell_budget=cell_budget, bucket=bucket, n_cols=n))
+    live = np.flatnonzero(counts > ell)
+    buckets = np.array([bucket_npr(int(d)) for d in counts[live]])
+    classes = []
+    for b in np.unique(buckets):
+        rows = live[buckets == b].astype(np.int32)
+        rows_b = min(max(CLASS_ROW_FLOOR, _pow2_ceil(rows.size)), n)
+        classes.append(DegreeClass(
+            np.pad(rows, (0, rows_b - rows.size), constant_values=-1),
+            *plan_level(int(counts[rows].max()), ell, rows_b, cell_budget=cell_budget,
+                        bucket=bucket, n_cols=n)))
+    if sum(k.launched for k in classes) > uniform.launched:
+        return [uniform]
+    return classes
+
+
+def claim_rows(sep, key, compact, rows, t_win, removed_slot, s_win, ell: int):
+    """Fold one class program's per-(row, slot) winners into the level's
+    carried claims: ``key`` (n, n) holds each row's least claim on each
+    edge so far, and ``sep[i, j, :ℓ]`` row i's set at that claim (an edge
+    alive at the level's start holds -1 in every slot, so the sepset tensor
+    carries the claims; :func:`commit_claims` settles both endpoints').
+
+    Within a class ranks ascend across programs and classes are
+    row-disjoint, so a (row, j) set is written only where no earlier
+    program claimed it. ``rows`` are global ids; padded rows claim nothing.
+    """
+    n = key.shape[0]
+    j_ids = jnp.clip(compact, 0, n - 1)
+    new = _slot_keys(rows, j_ids, t_win, removed_slot).astype(key.dtype)
+    first = new < key[rows[:, None], j_ids]
+    key = key.at[rows[:, None], j_ids].min(new)
+    j_write = jnp.where(first, j_ids, n)  # others → out of range, dropped
+    s_pad = jnp.pad(s_win, ((0, 0), (0, 0), (0, sep.shape[-1] - ell)), constant_values=-1)
+    sep = sep.at[rows[:, None], j_write].set(s_pad, mode="drop")
+    return sep, key
+
+
+@jax.jit
+def commit_claims(adj, sep, key):
+    """Commit a level's merged claims at once: each edge goes by the least
+    of its two endpoints' keys (the whole-level (rank, endpoint-order)
+    minimum, which the rank-ascending chunks of :func:`run_level` reach
+    too), and takes that endpoint's set."""
+    use_own = _use_own(key, key.T)[..., None]
+    return commit_adj(adj, key), jnp.where(use_own, sep, jnp.swapaxes(sep, 0, 1))
+
+
+def run_level_classes(c, adj, sep, ell: int, tau,
+                      cell_budget: int = DEFAULT_CELL_BUDGET, bucket: bool = True):
+    """One PC-stable level of the S-kernel engine as degree-class worklists
+    (:func:`plan_classes`).
+
+    Each program (kernels/ops.chunk_s_kernel_class) tests ranks
+    [t0, t0+n_chunk) of a class's rows against the level-start adjacency
+    and folds the winners in with :func:`claim_rows`; :func:`commit_claims`
+    then applies the level. Skeleton and sepsets are bit-identical to
+    :func:`run_level`. Same return contract; stats carry ``classes`` and
+    ``launched_tests`` in place of the level-wide ``n_chunk`` /
+    ``npr_bucket``; ``bucket`` as in :func:`plan_classes`.
+    """
+    from repro.kernels.ops import chunk_s_kernel_class
+
+    n = adj.shape[0]
+    tracer = obs.current()
+    with tracer.span("plan", level=ell):
+        counts_host = np.asarray(obs.fetch(jnp.sum(adj, axis=1), site="levels.plan"))
+        npr = int(counts_host.max(initial=0))
+        if npr - 1 < ell:
+            return adj, sep, {"skipped": True, "chunks": 0, "dispatches": 0,
+                              "npr": npr}
+        classes = plan_classes(counts_host, ell, cell_budget=cell_budget, bucket=bucket)
+        rows = [jnp.asarray(k.rows) for k in classes]
+        key = jnp.full((n, n), _imax(), _rank_dtype())
+    for k, rows_k in zip(classes, rows):
+        for t0 in range(0, k.total, k.n_chunk):
+            with tracer.span("chunk", cls=k.bucket, t0=t0):
+                sep, key = chunk_s_kernel_class(
+                    c, adj, sep, key, rows_k, jnp.asarray(t0, _rank_dtype()), tau,
+                    ell=ell, n_chunk=k.n_chunk, n_max=k.bucket)
+    adj, sep = commit_claims(adj, sep, key)
+    chunks = sum(k.chunks for k in classes)
+    return adj, sep, {
+        "skipped": False, "chunks": chunks, "npr": npr,
+        "total_sets": math.comb(npr, ell),
+        "classes": [{"bucket": k.bucket, "rows": int((k.rows >= 0).sum()),
+                     "rows_bucket": k.rows.size, "n_chunk": k.n_chunk,
+                     "total": k.total, "chunks": k.chunks} for k in classes],
+        "launched_tests": sum(k.launched for k in classes),
+        "dispatches": chunks + 1,
     }

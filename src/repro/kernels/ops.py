@@ -9,19 +9,21 @@ kernels want.
 Engine-selection matrix (who calls which kernel; registry in
 core/engines.py, jnp engines in core/levels.py):
 
-  engine     ℓ=1                          ℓ≥2                  code path
-  ─────────  ───────────────────────────  ───────────────────  ─────────────────
-  S          levels.chunk_s               levels.chunk_s       XLA einsums
-  E          levels.chunk_e               levels.chunk_e       XLA einsums
-  S-kernel   ops.chunk_s_kernel           ops.chunk_s_kernel   cholinv+cisweep
-  S-grid     ops.chunk_s_grid             ops.chunk_s_grid     sgrid (rank grid)
-  L1-dense   ops.level1_dense             (resolves to S)      level1 cube
-  auto       L1-dense                     S-kernel             fused production
+  engine     ℓ=1                          ℓ≥2                       code path
+  ─────────  ───────────────────────────  ────────────────────────  ─────────────────
+  S          levels.chunk_s               levels.chunk_s            XLA einsums
+  E          levels.chunk_e               levels.chunk_e            XLA einsums
+  S-kernel   ops.chunk_s_kernel           ops.chunk_s_kernel_class  cholinv+cisweep
+  S-grid     ops.chunk_s_grid             ops.chunk_s_grid          sgrid (rank grid)
+  L1-dense   ops.level1_dense             (resolves to S)           level1 cube
+  auto       L1-dense                     S-kernel                  fused production
 
-On TPU every ops.* path compiles through Mosaic; off-TPU the same kernels
-execute in Pallas interpret mode, so `auto` stays bit-identical across
-backends (the XLA gathers feeding the kernels are backend-native either
-way). corr.py backs `pc(x, corr="kernel")`; level0.py is the fused Alg. 3.
+S-kernel at ℓ≥2 runs one program per degree class and rank chunk
+(core/levels.run_level_classes). On TPU every ops.* path compiles through
+Mosaic; off-TPU the same kernels execute in Pallas interpret mode, so
+`auto` stays bit-identical across backends (the XLA gathers feeding the
+kernels are backend-native either way). corr.py backs
+`pc(x, corr="kernel")`; level0.py is the fused Alg. 3.
 """
 from __future__ import annotations
 
@@ -239,26 +241,58 @@ def chunk_s_grid(c, adj, sep, compact, counts, t0, tau, *, ell, n_chunk, n_max):
 
 
 # ------------------------------------- kernel-backed drop-in for levels.chunk_s
-@functools.partial(jax.jit, static_argnames=("ell", "n_chunk", "n_max"))
-def chunk_s_kernel(c, adj, sep, compact, counts, t0, tau, *, ell, n_chunk, n_max):
-    """Same contract as core.levels.chunk_s but the per-set inverse + CI sweep
-    run in the Pallas kernels (the unrank/gather/mask prologue is the SAME
-    levels.gather_s the jnp engine uses — gathers stay in XLA, which excels
-    at them, and the masking semantics can't diverge across engines)."""
+def _kernel_tests(c, adj, compact, counts, rows, ranks, tau, *, ell, n_max):
+    """levels._tests_s with the per-set inverse + CI sweep in the Pallas
+    kernels (the unrank/gather/mask prologue is the SAME levels.gather_s the
+    jnp engine uses — gathers stay in XLA, which excels at them, and the
+    masking semantics can't diverge across engines). Returns (sep_found
+    (n_l,T,npr) bool, s_ids (n_l,T,ell))."""
     from repro.core import levels as L
 
-    n, npr = compact.shape
-    rows = jnp.arange(n, dtype=jnp.int32)
-    ranks = t0 + jnp.arange(n_chunk, dtype=L._rank_dtype())
+    n_l, npr = compact.shape
+    n_chunk = ranks.shape[0]
     m2, ci_s, cj_s, cij, mask, s_ids = L.gather_s(
         c, adj, compact, counts, rows, ranks, ell=ell, n_max=n_max
     )
-
-    bsz = n * n_chunk
+    bsz = n_l * n_chunk
     sep_found = ci_shared(
         m2.reshape(bsz, ell, ell), ci_s.reshape(bsz, ell),
         cj_s.reshape(bsz, npr, ell), cij.reshape(bsz, npr),
         mask.reshape(bsz, npr), tau, ell=ell,
-    ).reshape(n, n_chunk, npr)
+    ).reshape(n_l, n_chunk, npr)
+    return sep_found, s_ids
 
+
+@functools.partial(jax.jit, static_argnames=("ell", "n_chunk", "n_max"))
+def chunk_s_kernel(c, adj, sep, compact, counts, t0, tau, *, ell, n_chunk, n_max):
+    """Same contract as core.levels.chunk_s but the per-set inverse + CI sweep
+    run in the Pallas kernels (:func:`_kernel_tests`)."""
+    from repro.core import levels as L
+
+    n = compact.shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    ranks = t0 + jnp.arange(n_chunk, dtype=L._rank_dtype())
+    sep_found, s_ids = _kernel_tests(c, adj, compact, counts, rows, ranks, tau,
+                                     ell=ell, n_max=n_max)
     return L._commit(c, adj, sep, compact, counts, sep_found, ranks, s_ids, None, ell)
+
+
+@functools.partial(jax.jit, static_argnames=("ell", "n_chunk", "n_max"))
+def chunk_s_kernel_class(c, adj, sep, key, rows, t0, tau, *, ell, n_chunk, n_max):
+    """One degree class's program of an S-kernel level ≥ 2
+    (core.levels.run_level_classes): compact the class's rows (global ids,
+    -1 padded) of the level-start ``adj`` to n′ = n_max slots, test ranks
+    [t0, t0+n_chunk) as :func:`chunk_s_kernel` does, and fold the winners
+    into the level's carried (sep, key) claims (levels.claim_rows). Nothing
+    is removed until levels.commit_claims."""
+    from repro.core import levels as L
+    from repro.core.compact import compact_rows
+
+    live = rows >= 0
+    rows = jnp.maximum(rows, 0)
+    compact, counts = compact_rows(adj[rows] & live[:, None], n_prime=n_max)
+    ranks = t0 + jnp.arange(n_chunk, dtype=L._rank_dtype())
+    sep_found, s_ids = _kernel_tests(c, adj, compact, counts, rows, ranks, tau,
+                                     ell=ell, n_max=n_max)
+    t_win, removed_slot, s_win = L._winners(sep_found, ranks, s_ids, None)
+    return L.claim_rows(sep, key, compact, rows, t_win, removed_slot, s_win, ell)

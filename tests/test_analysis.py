@@ -289,12 +289,21 @@ class TestRPR103:
         fs = J.stats_contract_findings(stats)  # pipelined => 2 * 2 = 4
         assert _codes(fs) == ["RPR103"]
 
+    def test_stats_contract_fires_on_class_chunks(self):
+        stats = [{"engine": "S-kernel", "total_sets": 820, "chunks": 3,
+                  "dispatches": 4, "classes": [{"chunks": 1}, {"chunks": 1}]}]
+        fs = J.stats_contract_findings(stats)  # its classes ran 2 chunks
+        assert _codes(fs) == ["RPR103"]
+
     def test_stats_contract_clean(self):
         stats = [
             {"engine": "S", "total_sets": 100, "n_chunk": 32, "chunks": 4,
              "dispatches": 4, "pipeline_depth": 1},
             {"engine": "S", "total_sets": 64, "n_chunk": 32, "chunks": 2,
              "dispatches": 4, "pipeline_depth": 2},
+            # a degree-class level: its classes' chunks and one commit
+            {"engine": "S-kernel", "total_sets": 820, "chunks": 3,
+             "dispatches": 4, "classes": [{"chunks": 1}, {"chunks": 2}]},
             {"skipped": True},
         ]
         assert J.stats_contract_findings(stats) == []
@@ -342,7 +351,7 @@ def test_skernel_entry_contract_regression():
 def test_entry_registry_covers_every_engine():
     """Every registered PC engine's traced surface has an analysis entry."""
     names = {e.name for e in J.entry_points()}
-    assert {"chunk_s", "chunk_e", "chunk_s_kernel", "chunk_s_grid",
+    assert {"chunk_s", "chunk_e", "chunk_s_kernel", "chunk_s_kernel_class", "chunk_s_grid",
             "chunk_g2", "chunk_g2_kernel", "level1_dense",
             "pc_scan"} <= names
 

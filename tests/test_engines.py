@@ -1,6 +1,8 @@
 """Engine registry: kernel-backed "auto" parity with the jnp S engine and
 the serial oracle, npr-bucketing invariance + boundary behaviour, and the
 compile-count probe for bucketed chunk planning."""
+import math
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -163,6 +165,109 @@ def test_grid_engine_multi_launch_parity():
     np.testing.assert_array_equal(g_run.adj, s_run.adj)
     np.testing.assert_array_equal(g_run.sepsets, s_run.sepsets)
     np.testing.assert_array_equal(g_run.cpdag, s_run.cpdag)
+
+
+# ------------------------------------- degree-class worklists (S-kernel, ℓ≥2)
+def _class_graph(kind: str, n: int = 48, seed: int = 0) -> np.ndarray:
+    """"hub": row 0 has 40 neighbours, every other row 1-8; "uniform": a
+    circulant graph, every row of degree 6 (one n′ bucket)."""
+    rng = np.random.default_rng(seed)
+    adj = np.zeros((n, n), bool)
+    if kind == "hub":
+        adj[0, 1:41] = True
+        for i in range(1, n):
+            adj[i, rng.choice(np.arange(41, n) if i < 41 else np.arange(1, n),
+                              size=rng.integers(1, 4), replace=False)] = True
+    else:
+        for k in (1, 2, 3):
+            adj[np.arange(n), (np.arange(n) + k) % n] = True
+    adj = adj | adj.T
+    np.fill_diagonal(adj, False)
+    return adj
+
+
+@pytest.mark.parametrize("ell", [2, 3])
+@pytest.mark.parametrize("kind,cell_budget,bucket", [
+    ("hub", L.DEFAULT_CELL_BUDGET, True),
+    ("hub", 2**20, True),  # the hub class takes several programs
+    ("uniform", L.DEFAULT_CELL_BUDGET, True),
+    ("hub", L.DEFAULT_CELL_BUDGET, False),  # exact widths, still classed
+], ids=["hub", "hub_chunked", "uniform", "hub_exact"])
+def test_degree_classes_bit_parity(kind, cell_budget, bucket, ell):
+    """The S-kernel level as degree-class worklists commits the same
+    skeleton and sepsets, bit for bit, as the level-wide jnp S plan; it
+    launches no more (row, set, neighbour) cells than that plan, strictly
+    fewer where the degrees are skewed; and its programs' jit keys come
+    from the bounded set (power-of-two rows and ranks, bucketed widths).
+    Without bucketing the level is classed all the same, at each class's
+    exact width."""
+    n, m = 48, 300
+    x, _ = sample_gaussian_dag(n=n, m=m, density=0.1, seed=3)
+    c = jnp.asarray(np.asarray(correlation_from_samples(jnp.asarray(x))))
+    adj = _class_graph(kind, n)
+    # the sepsets as level 0 leaves them: removed edges carry -2 in slot 0
+    sep = jnp.full((n, n, 8), -1, jnp.int32).at[:, :, 0].set(
+        jnp.where(jnp.asarray(adj), -1, -2))
+    tau = threshold(m, ell, 0.01)
+    a_s, s_s, st_s = L.run_level(c, jnp.asarray(adj), sep, ell, tau,
+                                 cell_budget=cell_budget, bucket=bucket)
+    a_k, s_k, st_k = engines.run_level(c, jnp.asarray(adj), sep, ell, tau,
+                                       engine="auto", cell_budget=cell_budget,
+                                       bucket=bucket)
+    assert np.asarray(adj & ~np.asarray(a_s)).any(), "the level removed nothing"
+    np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_s))
+    np.testing.assert_array_equal(np.asarray(s_k), np.asarray(s_s))
+
+    classes = st_k["classes"]
+    uniform = n * st_s["chunks"] * st_s["n_chunk"] * st_s["npr_bucket"]
+    assert st_k["launched_tests"] == sum(
+        k["rows_bucket"] * k["chunks"] * k["n_chunk"] * k["bucket"] for k in classes)
+    if kind == "hub":
+        assert st_k["launched_tests"] < uniform
+        assert len(classes) >= 3
+        assert sum(k["rows"] for k in classes) == int((adj.sum(1) > ell).sum())
+    else:  # one bucket: the level-wide plan itself
+        assert len(classes) == 1 and st_k["launched_tests"] == uniform
+    if cell_budget < L.DEFAULT_CELL_BUDGET:
+        assert max(k["chunks"] for k in classes) >= 2
+    assert st_k["chunks"] == sum(k["chunks"] for k in classes)
+    assert st_k["dispatches"] == st_k["chunks"] + 1
+    degrees = adj.sum(1)
+    for k in classes:
+        rb, t, b = k["rows_bucket"], k["n_chunk"], k["bucket"]
+        assert rb == n or (rb >= L.CLASS_ROW_FLOOR and rb & (rb - 1) == 0), k
+        assert k["rows"] <= rb and k["chunks"] == -(-k["total"] // t)
+        if bucket:
+            assert t & (t - 1) == 0 and b == min(L.bucket_npr(b), n), k
+            top = max(d for d in degrees if d > ell and min(L.bucket_npr(int(d)), n) == b)
+        else:  # the exact width: the largest degree among the class's rows
+            assert b in degrees, k
+            top = b
+        assert k["total"] == math.comb(int(top), ell), k  # the class's own rank range
+
+
+def test_plan_classes_never_launch_more_than_the_level_plan():
+    """Over random degree profiles the class plan launches at most the
+    cells of one level-wide plan over all n rows (it falls back to that
+    plan where power-of-two rounding would cost more)."""
+    rng = np.random.default_rng(0)
+    for trial in range(200):
+        n = int(rng.integers(8, 400))
+        ell = int(rng.integers(2, 4))
+        counts = rng.integers(0, max(ell + 2, n // int(rng.integers(1, 8))), size=n)
+        counts = np.minimum(counts, n - 1)
+        if counts.max() - 1 < ell:
+            continue
+        budget = int(2 ** rng.integers(12, 25))
+        classes = L.plan_classes(counts, ell, cell_budget=budget)
+        npr_b, n_chunk, total = L.plan_level(int(counts.max()), ell, n,
+                                             cell_budget=budget, n_cols=n)
+        uniform = n * -(-total // n_chunk) * n_chunk * npr_b
+        assert sum(k.launched for k in classes) <= uniform, (trial, n, ell)
+        rows = np.concatenate([k.rows for k in classes])
+        rows = rows[rows >= 0]
+        assert len(set(rows.tolist())) == rows.size  # classes are row-disjoint
+        assert set(np.flatnonzero(counts > ell)) <= set(rows.tolist())
 
 
 def test_plan_level_caps_and_rejects_unrepresentable_ranks():

@@ -440,7 +440,7 @@ def test_pc_span_tree(auto_runs, cap):
     assert set(run.timings_s) == names
     # each level first reads back its threshold; level 1 (dense kernel)
     # reads its own degree; levels >= 2 plan, then issue one chunk span per
-    # dispatched chunk program
+    # class program: the classes in order, ranks ascending within each
     spans = [r for r in recs if r["kind"] == "span"]
     assert _children(recs, "total/level0") == ["sync", "sync"]
     assert _children(recs, "total/level1") == ["sync", "degree", "sync"]
@@ -450,8 +450,10 @@ def test_pc_span_tree(auto_runs, cap):
         lv = f"total/level{st['level']}"
         assert _children(recs, lv) == (["sync", "plan"] + ["chunk"] * st["chunks"]
                                        + ["sync"])
-        t0s = [r["attrs"]["t0"] for r in spans if r["path"] == f"{lv}/chunk"]
-        assert t0s == list(range(0, st["total_sets"], st["n_chunk"]))
+        got = [(r["attrs"]["cls"], r["attrs"]["t0"]) for r in spans
+               if r["path"] == f"{lv}/chunk"]
+        assert got == [(k["bucket"], t0) for k in st["classes"]
+                       for t0 in range(0, k["total"], k["n_chunk"])]
     # every sync span names its site; the readback reads three arrays
     assert all("site" in r["attrs"] for r in spans if r["name"] == "sync")
     assert _children(recs, "total/readback") == ["sync"] * 3

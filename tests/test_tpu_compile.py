@@ -106,6 +106,38 @@ def _gsq(dev):
     return fn, (_spec(dev, (M, 4096), "int32"),)
 
 
+# (ℓ, rows bucket, n_chunk, n′ bucket) of the degree-class programs of the
+# nci60 benchmark's timed graphs (n=1190): the hub class, a mid class, the
+# many-row low-degree class at ℓ=2, and the one class of ℓ=3
+NCI60_N = 1190
+CLASS_KEYS = [(2, 8, 1024, 64), (2, 64, 512, 32), (2, 256, 8, 4), (3, 8, 4, 4)]
+
+
+@pytest.mark.parametrize("key", CLASS_KEYS, ids=[
+    "l{}_rows{}_t{}_npr{}".format(*k) for k in CLASS_KEYS])
+def test_degree_class_program_compiles_for_v5e(one_chip, monkeypatch, key):
+    """One degree-class program of the S-kernel route at ℓ ≥ 2 (XLA
+    compaction and gathers, cholinv + cisweep, the claim scatters), whole,
+    as the chip runs it."""
+    import jax
+
+    from repro.core import levels as L
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interp", lambda flag=None: False)
+    ell, rows_b, n_chunk, npr = key
+    n = NCI60_N
+    fn = functools.partial(ops.chunk_s_kernel_class.__wrapped__, ell=ell,
+                           n_chunk=n_chunk, n_max=npr)
+    rank = L._rank_dtype().dtype.name
+    compiled = jax.jit(fn).lower(
+        _spec(one_chip, (n, n)), _spec(one_chip, (n, n), "bool"),
+        _spec(one_chip, (n, n, 8), "int32"), _spec(one_chip, (n, n), rank),
+        _spec(one_chip, (rows_b,), "int32"), _spec(one_chip, (), rank),
+        _spec(one_chip, ())).compile()
+    assert len(MOSAIC.findall(compiled.as_text())) == 2
+
+
 @pytest.mark.parametrize("build,kernels", [
     (_corr, 1), (_level0, 1), (_level1, 1), (_cholinv_cisweep, 2),
     (_sgrid, 1), (_gsq, 1),
