@@ -4,6 +4,7 @@ started from, over the union of device operations from the start of each
 ``level1`` annotation to the start of the next one under ``total`` (see
 bench/trace_view.py on why a phase and not the annotation itself)."""
 from bench import work
+from bench.outputs import timings
 
 
 def read(run):
@@ -15,7 +16,7 @@ def read(run):
         return None
     needed, counted = 0, 0
     for g in run.graphs:
-        if g.output is None or "level1" not in g.output.timings_s:
+        if "level1" not in timings(g.output):
             continue
         needed += work.needed_tests(g.output, 1)
         counted += 1

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """Benchmark of PC-stable causal discovery (cuPC) on a TPU, driven through
-the program's public entry point ``repro.core.pc``.
+the program's public entry points: ``repro.core.pc``, or the one the cell's
+configuration names (bench/calls/<call>.py).
 
     python3 bench/run.py --workload nci60.full --seed 7 --seconds 30 --trace 0
 
@@ -8,8 +9,8 @@ One run of one cell (an entry of BENCHMARK.json's ``workloads``):
 
 1. set-up, timed as ``setup_s`` from the start of the process: read the
    cell's files (bench/registry.py), make its timed datasets (the same in
-   every run, bench/traffic.py), and run ``pc`` once on each, which
-   compiles every shape the window uses (JAX's persistent compilation
+   every run, bench/traffic.py), and run the cell's call once on each,
+   which compiles every shape the window uses (JAX's persistent compilation
    cache sits in the checkout, so only a checkout's first run compiles);
 2. the window: a closed loop submits the datasets, one graph in flight,
    pass after pass, each pass in a fresh seeded order, and starts no pass
@@ -64,7 +65,7 @@ class Graph:
 
     dataset: int
     latency_s: float
-    output: object  # the program's PCRun, None when the call raised
+    output: object  # the call's output (pc: a PCRun), None when the call raised
     error: str | None = None
 
 
@@ -122,10 +123,6 @@ def run_window(call, pool, mix, seed, seconds, annotate=None,
     return graphs, clock() - t0
 
 
-def same_output(a, b) -> bool:
-    return all((getattr(a, f) == getattr(b, f)).all() for f in ("adj", "sepsets", "cpdag"))
-
-
 def end_to_end(name: str, graphs, window_s: float, setup_s: float) -> float:
     """The end-to-end metrics, all from the host clock."""
     lat = [g.latency_s for g in graphs]
@@ -142,14 +139,15 @@ def verify(cell, pool, graphs, fresh, seed, root: Path = ROOT) -> dict:
     """The numbers ``correct`` compares: the reference's over a seeded
     sample of the timed datasets, and (prefixed ``fresh_``) over the fresh
     ones, given as (samples, output) pairs; outputs that differ from the
-    first output on the same dataset; graphs that raised."""
+    first output on the same dataset (the call's ``same``); graphs that
+    raised."""
     first, repeat = {}, 0
     for g in graphs:
         if g.output is None:
             continue
         if g.dataset not in first:
             first[g.dataset] = g.output
-        elif not same_output(g.output, first[g.dataset]):
+        elif not cell.call.same(g.output, first[g.dataset]):
             repeat += 1
         else:
             g.output = first[g.dataset]  # identical: keep one copy
@@ -170,16 +168,9 @@ def verify(cell, pool, graphs, fresh, seed, root: Path = ROOT) -> dict:
 
 
 def program(cell, seed: int):
-    """The system under test: the call the window times, ``pc`` as a user
-    calls it with the cell's options."""
-    from repro.core import pc
-
-    options = traffic.pc_options(cell.config, cell.traffic)
-
-    def call(x):
-        return pc(x, engine="auto", corr="kernel", **options)
-
-    return call
+    """The system under test: the call the window times, as the cell's call
+    module makes it."""
+    return cell.call.make(cell, seed)
 
 
 def memory_peak(jax) -> int:

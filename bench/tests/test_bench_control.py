@@ -22,7 +22,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import control  # noqa: E402
+from bench import control, registry  # noqa: E402
 from test_bench_harness import LIMITS, make_root  # noqa: E402
 
 
@@ -61,7 +61,7 @@ def test_control_output_layout(jnp_pc):
 
     x = sample(30, 200, 0.2, 5).astype(np.float32)
     cfg, mix = {"alpha": 0.01}, {"max_level": None, "sepset_depth": 8, "orient": True}
-    out = control.control_output(x, cfg, mix)
+    out = registry.load_call({}).control_output(x, cfg, mix)
     prog = jnp_pc(x, alpha=0.01)
     assert out.sepsets.shape == prog.sepsets.shape and out.adj.dtype == bool
     assert (out.adj == prog.adj).all()

@@ -1,12 +1,14 @@
 """The benchmark harness on the CPU: discovery by name, trace reduction,
 the end-to-end arithmetic, and whole runs (chip look skipped) that must
-come out correct on the sound program and not correct on broken ones."""
+come out correct on the sound program and not correct on broken ones; a
+stand-in entry point, added as files only, runs through the same harness."""
 from __future__ import annotations
 
 import json
 import shutil
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -15,7 +17,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import registry, run, traffic  # noqa: E402
-from bench.trace_view import TraceView, union  # noqa: E402
+from bench.trace_view import TraceView, clipped, union  # noqa: E402
 
 TINY = {"name": "tiny", "n": 24, "m": 300, "density": 0.2, "alpha": 0.01,
         "law": "gaussian_dag", "reference": "pc_stable", "data_seed": 24}
@@ -24,11 +26,69 @@ MIX = {"name": "tiny_mix", "pool": 2, "max_level": None, "sepset_depth": 8, "ori
 LIMITS = {"z_gap": 0.01, "bad": 0, "cpdag_diff": 0, "fresh_z_gap": None, "fresh_bad": 0,
           "fresh_cpdag_diff": 0, "repeat_diff": 0, "failed": 0}
 
+# A stand-in entry point: categorical codes in, per-column sums out (an
+# output with no adj, sepsets, spans or level records), its own mix key
+# ``scale``, its own repeat check, control, planted fault, data law and
+# reference. Its control sums in int8, which a column of 100 codes at scale
+# 2 overflows.
+CODES = {"name": "codes", "n": 5, "m": 100, "density": 0.0, "law": "codes",
+         "reference": "colsum", "call": "colsum", "dtype": "int32", "data_seed": 3}
+CODES_MIX = {"name": "codes_mix", "pool": 3, "check_pool": 2, "fresh": 1, "scale": 2}
+CODES_LIMITS = {"total_diff": 0, "fresh_total_diff": 0, "repeat_diff": 0, "failed": 0}
+STAND_IN = {
+    "calls/colsum.py": """
+from types import SimpleNamespace
+
+import numpy as np
+
+KEYS = {"scale"}
+
+
+def make(cell, seed):
+    k = cell.traffic["scale"]
+    return lambda x: SimpleNamespace(total=np.asarray(x, np.int64).sum(axis=0) * k)
+
+
+def same(a, b):
+    return bool((a.total == b.total).all())
+
+
+def control(cell, seed):
+    k = cell.traffic["scale"]
+    return lambda x: SimpleNamespace(total=(np.asarray(x, np.int8) * k).sum(axis=0,
+                                                                           dtype=np.int8))
+
+
+def flip(cell, seed):
+    call = make(cell, seed)
+
+    def flipped(x):
+        total = call(x).total.copy()
+        total[seed % len(total)] += 1
+        return SimpleNamespace(total=total)
+
+    return flipped
+""",
+    "reference/colsum.py": """
+def verify(config, traffic, x, output, threads=1):
+    want = [traffic["scale"] * sum(int(v) for v in col) for col in x.T]
+    return {"total_diff": max(abs(int(a) - b) for a, b in zip(output.total, want))}
+""",
+    "data/codes.py": """
+import numpy as np
+
+
+def sample(n, m, density, seed, network):
+    return np.random.default_rng([network, *seed]).integers(0, 3, size=(m, n))
+""",
+}
+
 
 def make_root(tmp_path: Path) -> Path:
     """A checkout holding the benchmark's code and one more configuration,
     traffic mix, per-layer metric and cell, each added only as files and
-    entries."""
+    entries; and a cell of a stand-in entry point (``codes.codes_mix``),
+    likewise only files and entries."""
     root = tmp_path / "checkout"
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
@@ -38,6 +98,15 @@ def make_root(tmp_path: Path) -> Path:
     (root / "bench" / "limits" / "tiny.tiny_mix.json").write_text(json.dumps(LIMITS))
     (root / "bench" / "metrics" / "graphs_seen.py").write_text(
         "def read(run):\n    return len(run.graphs)\n")
+    for rel, text in STAND_IN.items():
+        (root / "bench" / rel).write_text(text)
+    (root / "bench" / "configs" / "codes.json").write_text(json.dumps(CODES))
+    (root / "bench" / "traffic" / "codes_mix.json").write_text(json.dumps(CODES_MIX))
+    (root / "bench" / "limits" / "codes.codes_mix.json").write_text(json.dumps(CODES_LIMITS))
+    bm["configs"].append({"name": "codes", "source": "test", "file": "bench/configs/codes.json",
+                          "reduced": [], "why": "test"})
+    bm["workloads"].append({"name": "codes.codes_mix", "config": "codes",
+                            "traffic": "codes_mix", "chips": 1, "why": "test"})
     bm["configs"].append({"name": "tiny", "source": "test", "file": "bench/configs/tiny.json",
                           "reduced": [], "why": "test"})
     bm["workloads"].append({"name": "tiny.tiny_mix", "config": "tiny",
@@ -94,6 +163,70 @@ def test_trace_reduction_busy_and_idle():
         ("total | Transpose::Execute", 0.025), ("total", 0.02)]
     top = view.top_ops(lo, hi)
     assert top[0] == ["l2", pytest.approx(0.03)]
+
+
+def _profile(*planes):
+    """A stand-in for jax.profiler.ProfileData: planes of lines of events
+    (name, start_ns, duration_ns)."""
+    ev = lambda n, t, d: SimpleNamespace(name=n, start_ns=t, duration_ns=d)  # noqa: E731
+    return SimpleNamespace(planes=[
+        SimpleNamespace(name=name, lines=[SimpleNamespace(name=ln, events=[ev(*e) for e in evs])
+                                          for ln, evs in lines.items()])
+        for name, lines in planes])
+
+
+def test_empty_device_plane_is_no_device():
+    """A v5e trace carries an empty /device:CUSTOM:Megascale Trace plane:
+    busy time, idle share, top operations and idle gaps read the TPU alone."""
+    ms = 1_000_000
+    tpu = ("/device:TPU:0", {"XLA Ops": [("a", 10 * ms, 20 * ms), ("b", 50 * ms, 10 * ms)]})
+    host = ("/host:CPU", {"python": [("bench_window", 0, 100 * ms),
+                                     ("total/level1", 5 * ms, 60 * ms)]})
+    empty = ("/device:CUSTOM:Megascale Trace", {"Steps": []})
+    views = [TraceView.from_profile(_profile(*planes))
+             for planes in ([tpu, host], [empty, tpu, host], [tpu, empty, host])]
+    idle = registry.metric_reader("device_idle_share")
+    for view in views:
+        run_ = SimpleNamespace(trace=view, window=(0, 100 * ms))
+        assert list(view.chips) == ["/device:TPU:0"]
+        assert view.busy_ns([(0, 100 * ms)]) == 30 * ms
+        assert idle(run_) == pytest.approx(70.0)
+        assert view.top_ops(0, 100 * ms) == [["a", 0.02], ["b", 0.01]]
+        assert view.idle_gaps(0, 100 * ms) == views[0].idle_gaps(0, 100 * ms)
+    assert registry.metric_reader("device_idle_share")(
+        SimpleNamespace(trace=TraceView.from_profile(_profile(empty, host)),
+                        window=(0, 100 * ms))) is None
+
+
+def test_idle_chip_counts_as_idle():
+    """A chip that ran nothing is a device: with two TPU planes, one of them
+    empty, busy time and idle share are the average of the two chips."""
+    ms = 1_000_000
+    tpu0 = ("/device:TPU:0", {"XLA Ops": [("a", 10 * ms, 20 * ms), ("b", 50 * ms, 10 * ms)]})
+    tpu1 = ("/device:TPU:1", {"XLA Ops": []})
+    host = ("/host:CPU", {"python": [("bench_window", 0, 100 * ms),
+                                     ("total/level1", 5 * ms, 60 * ms)]})
+    view = TraceView.from_profile(_profile(tpu0, tpu1, host))
+    run_ = SimpleNamespace(trace=view, window=(0, 100 * ms))
+    assert list(view.chips) == ["/device:TPU:0", "/device:TPU:1"]
+    assert view.busy_ns([(0, 100 * ms)]) == 15 * ms
+    assert registry.metric_reader("device_idle_share")(run_) == pytest.approx(85.0)
+    assert view.top_ops(0, 100 * ms) == [["a", 0.01], ["b", 0.005]]
+    # idle 70 + 100 ms, of which total/level1 ([5, 65) ms) covers 30 + 60 ms
+    assert registry.metric_reader("idle_unattributed_share")(run_) == pytest.approx(
+        100.0 * (170 - 90) / 170)
+
+
+def test_clipped_equals_the_plain_sum():
+    """``clipped``'s bisection gives the sum over every interval it would
+    have visited one by one."""
+    rng = np.random.default_rng(2**31 + 11)
+    for _ in range(200):
+        starts = rng.integers(0, 1000, size=rng.integers(0, 30))
+        merged = union((int(a), int(a + rng.integers(1, 80))) for a in starts)
+        lo, hi = sorted(int(t) for t in rng.integers(-50, 1100, size=2))
+        plain = sum(max(0, min(b, hi) - max(a, lo)) for a, b in merged)
+        assert clipped(merged, lo, hi) == plain
 
 
 class FakeClock:
@@ -242,8 +375,104 @@ def test_no_chip_exits_without_result(capsys):
     assert capsys.readouterr().out == ""
 
 
+def _with_key(root, mix, key):
+    """The mix file ``mix`` with one more key."""
+    path = root / "bench" / "traffic" / f"{mix}.json"
+    path.write_text(json.dumps({**json.loads(path.read_text()), key: 2}))
+
+
 def test_mix_key_the_generator_ignores_is_refused(tmp_path):
     root = make_root(tmp_path)
-    cell = registry.load_cell("tiny.tiny_mix", root)
+    _with_key(root, "tiny_mix", "in_flight")
     with pytest.raises(KeyError, match="in_flight"):
-        traffic.make_pool(cell.config, {**cell.traffic, "in_flight": 2}, root)
+        registry.load_cell("tiny.tiny_mix", root)
+
+
+def test_mix_key_of_another_call_is_refused(tmp_path):
+    """``scale`` is the stand-in call's key: taken there, refused for pc,
+    and a key that neither the generator nor the call acts on is refused."""
+    root = make_root(tmp_path)
+    codes = registry.load_cell("codes.codes_mix", root)
+    assert len(traffic.make_pool(codes.config, codes.traffic, root)) == 3
+    _with_key(root, "codes_mix", "in_flight")
+    with pytest.raises(KeyError, match="in_flight"):
+        registry.load_cell("codes.codes_mix", root)
+    _with_key(root, "tiny_mix", "scale")
+    with pytest.raises(KeyError, match="scale"):
+        registry.load_cell("tiny.tiny_mix", root)
+
+
+def test_config_dtype_gives_integer_datasets(tmp_path):
+    root = make_root(tmp_path)
+    cell = registry.load_cell("codes.codes_mix", root)
+    pool = traffic.make_pool(cell.config, cell.traffic, root)
+    fresh = traffic.make_fresh(cell.config, cell.traffic, 2**31 + 9, root)
+    for x in pool + fresh:
+        assert x.dtype == np.int32 and x.shape == (100, 5)
+        assert set(np.unique(x)) <= {0, 1, 2}
+
+
+@pytest.mark.parametrize("cell, kw", [
+    ("nci60.full", {"max_level": None, "sepset_depth": 8, "orient": True}),
+    ("dream5.order1", {"max_level": 1, "sepset_depth": 1, "orient": False}),
+    ("nci60.order1", {"max_level": 1, "sepset_depth": 1, "orient": False}),
+])
+def test_pc_call_issues_the_same_pc_call(monkeypatch, cell, kw):
+    """The committed cells name no call, so they time ``pc`` through
+    bench/calls/pc.py with exactly these arguments."""
+    from repro import core
+
+    seen = []
+    monkeypatch.setattr(core, "pc", lambda x, **a: seen.append((x, a)) or "out")
+    c = registry.load_cell(cell)
+    assert "call" not in c.config and c.call.__file__.endswith("calls/pc.py")
+    assert run.program(c, 3)("x") == "out"
+    assert seen == [("x", {"engine": "auto", "corr": "kernel", "alpha": 0.01, **kw})]
+
+
+def stand_in_run(tmp_path, capsys, trace=0, make_call=run.program):
+    root = make_root(tmp_path)
+    rc = run.main(["--workload", "codes.codes_mix", "--seed", str(2**31 + 3), "--seconds", "0",
+                   "--trace", str(trace)], root=root, need_chip=False, make_call=make_call)
+    return rc, json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_another_entry_point_runs_correct(tmp_path, capsys, trace):
+    """A call module with its own output, mix key, ``same`` and reference
+    runs through run.main to a correct result; the per-layer metrics that
+    every cell reports find nothing of theirs to read and are left out."""
+    rc, res = stand_in_run(tmp_path, capsys, trace)
+    assert rc == 0 and res["correct"] is True
+    assert res["attempted"] == 3 and res["failed"] == 0
+    assert res["checks"] == {name: {"value": 0, "limit": 0} for name in CODES_LIMITS}
+    if trace:
+        assert res["metrics"] == {} and "device_ops" in res["breakdown"]
+    else:
+        assert set(res["metrics"]) == {"graph_s", "setup_s"}
+
+
+def test_another_entry_point_wrong_answer_is_not_correct(tmp_path, capsys):
+    def off_by_one(cell, seed):
+        call = run.program(cell, seed)
+        return lambda x: SimpleNamespace(total=call(x).total + 1)
+
+    rc, res = stand_in_run(tmp_path, capsys, make_call=off_by_one)
+    assert rc == 0 and res["correct"] is False
+    assert res["checks"]["total_diff"]["value"] == 1
+
+
+def test_another_entry_point_brings_its_own_roles(tmp_path, capsys):
+    """bench/control.py finds the control and the planted fault in the
+    stand-in's call module: the program reads correct, both others not."""
+    from bench import control
+
+    root = make_root(tmp_path)
+    seed = 2**31 + 21
+    rc = control.main(["--workload", "codes.codes_mix", "--as", "program", "control", "flip",
+                       "--seeds", str(seed)], root=root, need_chip=False)
+    assert rc == 0
+    prog, ctl, flip = (json.loads(ln) for ln in capsys.readouterr().out.strip().splitlines()[1::2])
+    assert prog["correct"] is True
+    assert ctl["correct"] is False and ctl["checks"]["total_diff"]["value"] == 256
+    assert flip["correct"] is False and flip["checks"]["total_diff"]["value"] == 1

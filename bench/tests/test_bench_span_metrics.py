@@ -43,7 +43,7 @@ def test_readers_are_registered_for_the_cells_they_list():
         assert callable(reader(name))
     assert per_layer["chunk_dispatch_s"]["workloads"] == ["nci60.full"]
     for name in set(READERS) - {"chunk_dispatch_s"}:
-        assert per_layer[name]["workloads"] == ["nci60.full", "dream5.order1"]
+        assert per_layer[name]["workloads"] == ["nci60.full", "dream5.order1", "nci60.order1"]
 
 
 def test_span_readers_average_over_graphs():
@@ -106,3 +106,17 @@ def test_idle_unattributed_share_reads_nothing_without_annotations():
     busy = TraceView(devices={"/device:TPU:0": [("a", 0, 100 * MS)]},
                      host=[("total/level1", 0, 100 * MS)])
     assert reader("idle_unattributed_share")(a_run([], busy)) is None
+
+
+@pytest.mark.parametrize("name", [m["name"] for m in registry.load_benchmark()["per_layer"]])
+def test_reader_of_another_calls_output_reads_nothing(name):
+    """Outputs of another entry point keep no spans, counts, level records
+    or skeleton, and the trace holds none of the program's annotations:
+    every reader gives None and none raises, except the device's idle share,
+    which reads the trace alone."""
+    view = TraceView(devices={"/device:TPU:0": [("op", 10 * MS, 40 * MS)]},
+                     host=[("bench_window", 0, 100 * MS), ("graph", 0, 90 * MS)])
+    run = a_run([SimpleNamespace(output=SimpleNamespace(total=[1, 2])),
+                 SimpleNamespace(output=None)], view)
+    expect = pytest.approx(70.0) if name == "device_idle_share" else None
+    assert reader(name)(run) == expect

@@ -18,16 +18,22 @@ is therefore read over its *phase*: from its annotation's start to the
 start of the next annotation under the same parent, or the end of the
 enclosing ``graph`` annotation.
 
-Busy time is the union of a device's operation intervals; with several
-devices it is averaged over them.
+Busy time is the union of a chip's operation intervals; with several
+chips it is averaged over them. A chip is a ``/device:`` plane that is not
+a ``/device:CUSTOM:`` one: a v5e trace carries an empty
+``/device:CUSTOM:Megascale Trace`` plane beside ``/device:TPU:0``, which
+would halve every device time and idle share, while a chip that ran
+nothing still counts, as idle.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 from dataclasses import dataclass, field
 
 DEVICE_PREFIX = "/device:"
+CUSTOM_PREFIX = "/device:CUSTOM:"
 HOST_PREFIX = "/host:"
 OP_LINE = "XLA Ops"
 WINDOW = "bench_window"
@@ -46,8 +52,15 @@ def union(intervals) -> list:
 
 
 def clipped(merged, lo, hi) -> float:
-    """Length of disjoint intervals inside [lo, hi]."""
-    return float(sum(max(0.0, min(b, hi) - max(a, lo)) for a, b in merged))
+    """Length of disjoint sorted intervals (as ``union`` gives them) inside
+    [lo, hi]; visits only the intervals that reach into it."""
+    total = 0
+    i = bisect.bisect_right(merged, lo, key=lambda ab: ab[1])
+    while i < len(merged) and merged[i][0] < hi:
+        a, b = merged[i]
+        total += min(b, hi) - max(a, lo)
+        i += 1
+    return float(total)
 
 
 def _parent(name: str) -> str:
@@ -56,7 +69,7 @@ def _parent(name: str) -> str:
 
 @dataclass
 class TraceView:
-    #: per device plane: [(op name, start_ns, end_ns)]
+    #: per ``/device:`` plane: [(op name, start_ns, end_ns)]
     devices: dict = field(default_factory=dict)
     #: [(annotation name, start_ns, end_ns)]
     host: list = field(default_factory=list)
@@ -80,9 +93,9 @@ class TraceView:
             lines = list(plane.lines)
             if plane.name.startswith(DEVICE_PREFIX):
                 ops = [ln for ln in lines if ln.name == OP_LINE] or lines
-                view.devices[plane.name] = [
-                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                    for ln in ops for e in ln.events if e.duration_ns > 0]
+                view.devices[plane.name] = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                                            for ln in ops for e in ln.events
+                                            if e.duration_ns > 0]
             elif plane.name.startswith(HOST_PREFIX):
                 for ln in lines:
                     evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
@@ -92,6 +105,12 @@ class TraceView:
                     else:
                         view.runtime += evs
         return view
+
+    @property
+    def chips(self) -> dict:
+        """The device planes that are chips: every one but the
+        ``/device:CUSTOM:`` planes, with or without operations."""
+        return {k: v for k, v in self.devices.items() if not k.startswith(CUSTOM_PREFIX)}
 
     def spans(self, name: str) -> list:
         """(start, end) of every annotation called ``name`` (or whose slash
@@ -117,35 +136,36 @@ class TraceView:
 
     def busy_ns(self, intervals) -> float:
         """Device-busy time inside the given disjoint host intervals,
-        averaged over the devices."""
-        if not self.devices:
+        averaged over the chips."""
+        chips = self.chips
+        if not chips:
             return 0.0
         total = 0.0
-        for ops in self.devices.values():
+        for ops in chips.values():
             merged = union((a, b) for _, a, b in ops)
             total += sum(clipped(merged, lo, hi) for lo, hi in intervals)
-        return total / len(self.devices)
+        return total / len(chips)
 
     def top_ops(self, lo, hi, k: int = 10) -> list:
         """[name, seconds] of the k operations with the most device time
-        inside [lo, hi], averaged over the devices."""
-        per = {}
-        for ops in self.devices.values():
+        inside [lo, hi], averaged over the chips."""
+        chips, per = self.chips, {}
+        for ops in chips.values():
             for name, a, b in ops:
                 d = max(0.0, min(b, hi) - max(a, lo))
                 if d > 0:
                     per[name] = per.get(name, 0.0) + d
         top = sorted(per.items(), key=lambda t: -t[1])[:k]
-        return [[name, ns / 1e9 / len(self.devices)] for name, ns in top]
+        return [[name, ns / 1e9 / len(chips)] for name, ns in top]
 
     def idle_gaps(self, lo, hi, k: int = 10) -> list:
         """[what the host was doing, seconds] of the k longest stretches
-        inside [lo, hi] in which the first device ran nothing: the
+        inside [lo, hi] in which the first chip ran nothing: the
         innermost annotation around the gap's middle, and after a ``|``
         the runtime event that covers most of the gap, if any."""
-        if not self.devices:
+        if not self.chips:
             return []
-        ops = next(iter(self.devices.values()))
+        ops = next(iter(self.chips.values()))
         merged = union((max(a, lo), min(b, hi)) for _, a, b in ops if b > lo and a < hi)
         edges = [lo] + [t for ab in merged for t in ab] + [hi]
         gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)

@@ -8,24 +8,23 @@ a job and waits for it). A traffic mix (bench/traffic/<mix>.json) sets:
 
     pool        the timed datasets, made in set-up; every pass of the
                 window submits each of them once, in a fresh seeded order
-    max_level   deepest PC level run (null: until no row has enough
-                neighbours or the sepset depth is reached)
-    sepset_depth
-                slots per separating set in the output: the largest set a
-                run can record
-    orient      orient the skeleton into a CPDAG
     check_pool  timed datasets, drawn from the seed, whose output is held
                 to the reference
     fresh       datasets drawn from the seed, run through the same call
                 after the window and held to the reference
 
+and the keys the configuration's call acts on (``KEYS`` of
+bench/calls/<call>.py; for ``pc``: max_level, sepset_depth, orient).
+
 A configuration (bench/configs/<config>.json) names its data law
 (bench/data/<law>.py, a ``sample(n, m, density, seed, network)``
-function), its sizes, and ``data_seed``: the network and the timed
-datasets come from it, so that every run times the same work, and the
-seed changes the order, the datasets checked and the fresh datasets.
+function), its sizes, the ``dtype`` of its sample matrices (default
+float32), and ``data_seed``: the network and the timed datasets come from
+it, so that every run times the same work, and the seed changes the order,
+the datasets checked and the fresh datasets.
 
-A mix with any other key is refused: this generator would not act on it.
+A mix with a key that neither this generator nor the call acts on is
+refused when the cell is loaded (bench/registry.py).
 """
 from __future__ import annotations
 
@@ -33,8 +32,8 @@ import numpy as np
 
 from bench import registry
 
-#: the keys a mix may set; ``name`` and ``why`` describe it
-KEYS = {"name", "why", "pool", "max_level", "sepset_depth", "orient", "check_pool", "fresh"}
+#: the generator's own mix keys; ``name`` and ``why`` describe the mix
+KEYS = {"name", "why", "pool", "check_pool", "fresh"}
 
 
 def law(config: dict, root=registry.ROOT):
@@ -45,15 +44,12 @@ def law(config: dict, root=registry.ROOT):
 def _draw(config: dict, seed, root) -> np.ndarray:
     return law(config, root).sample(
         config["n"], config["m"], config["density"], seed=seed,
-        network=config["data_seed"]).astype(np.float32)
+        network=config["data_seed"]).astype(config.get("dtype", "float32"))
 
 
 def make_pool(config: dict, traffic: dict, root=registry.ROOT) -> list:
-    """The timed datasets: (m, n) float32 sample matrices, the k-th drawn
-    from the seed sequence (data_seed, k)."""
-    unknown = set(traffic) - KEYS
-    if unknown:
-        raise KeyError(f"bench/traffic.py acts on no mix key {sorted(unknown)}")
+    """The timed datasets: (m, n) sample matrices, the k-th drawn from the
+    seed sequence (data_seed, k)."""
     return [_draw(config, [config["data_seed"], k], root)
             for k in range(int(traffic["pool"]))]
 
@@ -77,8 +73,3 @@ def checked(traffic: dict, seed: int) -> list:
     return sorted(int(i) for i in rng.choice(k, size=min(int(traffic["check_pool"]), k),
                                               replace=False))
 
-
-def pc_options(config: dict, traffic: dict) -> dict:
-    """The keyword arguments of the program's ``pc`` call."""
-    return {"alpha": config["alpha"], "max_level": traffic["max_level"],
-            "sepset_depth": traffic["sepset_depth"], "orient": traffic["orient"]}
